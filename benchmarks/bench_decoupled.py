@@ -1,6 +1,7 @@
 """Decoupled (Pallas-kernel) fast path vs coupled simulator: accuracy of
 the first-order approximation and its speedup — the quantified trade of
-DESIGN.md §3 (TPU-native rethink)."""
+DESIGN.md §3 (TPU-native rethink).  The kernels compile for the TPU; off
+the TPU this module fails rather than interpret them."""
 from __future__ import annotations
 
 import numpy as np

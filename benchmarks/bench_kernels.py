@@ -1,6 +1,7 @@
-"""Kernel microbenchmarks: Pallas (interpret mode on CPU — the TPU program
-is identical) vs the pure-jnp oracle, on paper-scale port counts (20800
-directed port-ends), plus oracle-parity checks."""
+"""Kernel microbenchmarks: the decoupled evaluator's Pallas kernels,
+compiled for the TPU, vs the pure-jnp oracle, on paper-scale port counts
+(20800 directed port-ends), plus oracle-parity checks.  Off the TPU the
+kernel rows fail: nothing falls back to interpret mode."""
 from __future__ import annotations
 
 import numpy as np

@@ -60,6 +60,8 @@ def main() -> None:
     ap.add_argument("--json-dir", default=None, metavar="DIR",
                     help="write BENCH_<name>.json perf records to DIR")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     if args.json_dir:
         os.makedirs(args.json_dir, exist_ok=True)

@@ -19,6 +19,7 @@ import sys
 sys.path.insert(0, "src")
 
 from repro.core.eee import Policy                          # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.power_advisor import advise_stream       # noqa: E402
 from repro.topology.megafly import small_topology          # noqa: E402
 
@@ -49,6 +50,7 @@ def main():
                          "instead of the fixed racing pool")
     ap.add_argument("--json", default=None, metavar="OUT")
     args = ap.parse_args()
+    enable_compile_cache()
 
     topo = small_topology(n_groups=3, leaves=2, spines=2,
                           nodes_per_leaf=2) if args.tiny else None

@@ -11,6 +11,9 @@ from repro.core.eee import Policy, PowerModel
 from repro.core.simulator import compare_policies
 from repro.topology.megafly import paper_topology
 from repro.traffic import generators as G
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 topo = paper_topology()
 pm = PowerModel()

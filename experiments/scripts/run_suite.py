@@ -33,6 +33,7 @@ from repro import scenarios as SC
 from repro.core.eee import Policy
 from repro.core.sweep import group_policies
 from repro.distributed import shard_sweep
+from repro.launch.compile_cache import enable_compile_cache
 from repro.topology.megafly import paper_topology, small_topology
 from repro.traffic.plan import PACKINGS, format_cache_info
 
@@ -97,6 +98,7 @@ def main():
                          "devices (repro.distributed.shard_sweep)")
     ap.add_argument("--csv", default=None, metavar="PATH")
     args = ap.parse_args()
+    enable_compile_cache()
 
     topo = get_topo(args.scale)
     names = None

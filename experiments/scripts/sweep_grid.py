@@ -22,6 +22,9 @@ from repro.core.sweep import group_policies, sweep_policies
 from repro.topology.megafly import paper_topology, small_topology
 from repro.traffic import generators as G
 from repro.traffic.plan import compile_plan
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 scale = sys.argv[1] if len(sys.argv) > 1 else "small"
 if scale not in ("small", "paper"):

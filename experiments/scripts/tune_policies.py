@@ -35,6 +35,7 @@ from run_suite import get_topo
 from repro import scenarios as SC
 from repro import tuning
 from repro.distributed import shard_sweep
+from repro.launch.compile_cache import enable_compile_cache
 from repro.scenarios.catalog import FAMILIES
 from repro.traffic.plan import PACKINGS, format_cache_info
 
@@ -72,6 +73,7 @@ def main():
                          "devices (repro.distributed.shard_sweep)")
     ap.add_argument("--csv", default=None, metavar="PATH")
     args = ap.parse_args()
+    enable_compile_cache()
 
     topo = get_topo(args.scale)
     names = None

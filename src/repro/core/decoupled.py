@@ -137,12 +137,14 @@ def events_to_streams(events, n_links, t_end):
 
 
 def evaluate_fixed(gaps, durs, tail, t_pdt, policy: Policy,
-                   pm: PowerModel, use_ref=False):
+                   pm: PowerModel, use_ref=False, interpret=False):
     """Evaluate a per-port (or scalar) t_PDT assignment.  Returns dict.
 
     Dual-capable policies (``dual``/``coalesce``/``perfbound_dual``)
     evaluate the two-row ladder: gaps outlasting the demotion timer land
-    in the deep row's time/energy accounts.
+    in the deep row's time/energy accounts.  ``use_ref`` runs the jnp
+    oracle, ``interpret`` the kernel in Pallas interpret mode; otherwise
+    the kernel compiles for the TPU (and raises on any other backend).
     """
     P = gaps.shape[1]
     tpdt = jnp.broadcast_to(jnp.asarray(t_pdt, jnp.float32), (P,))
@@ -151,7 +153,8 @@ def evaluate_fixed(gaps, durs, tail, t_pdt, policy: Policy,
     hold = policy.hold_delay if policy.kind == "precoalesce" else 0.0
     out = ops.port_energy_op(gaps, durs, tpdt, tail, t_w=st.t_w, t_s=st.t_s,
                              t_w2=st2.t_w, t_s2=st2.t_s, t_dst=t_dst,
-                             hold=hold, use_ref=use_ref)
+                             hold=hold, use_ref=use_ref,
+                             interpret=interpret)
     link_energy = 2 * pm.port_power * (
         out["time_wake"].sum() + st.power_frac * out["time_sleep"].sum()
         + st2.power_frac * out["time_sleep2"].sum())
@@ -162,13 +165,13 @@ def evaluate_fixed(gaps, durs, tail, t_pdt, policy: Policy,
 
 
 def perfbound_snapshot_tpdt(gaps, t_elapsed, hop_mean, policy: Policy,
-                            use_ref=False):
+                            use_ref=False, interpret=False):
     """One-shot PerfBound prediction from the full gap history (the
     'periodic batched recalculation' mode of §3.2, kernel-accelerated)."""
     counts, sums = ops.hist_update_op(
         gaps, n_bins=policy.hist_bins, bin_width=policy.hist_bin_width,
         log_bins=policy.hist_log_bins, log_min=policy.hist_log_min,
-        log_max=policy.hist_log_max, use_ref=use_ref)
+        log_max=policy.hist_log_max, use_ref=use_ref, interpret=interpret)
     l = policy.bound / max(hop_mean, 1.0)
     N = jnp.full(counts.shape[:1], l * t_elapsed / policy.state.t_w,
                  jnp.float32)
@@ -176,13 +179,15 @@ def perfbound_snapshot_tpdt(gaps, t_elapsed, hop_mean, policy: Policy,
     total = counts.sum(-1)
     return ops.tpdt_select_op(counts, sums, N, total, centers,
                               max_tpdt=policy.max_tpdt,
-                              tpdt_init=policy.tpdt_init, use_ref=use_ref)
+                              tpdt_init=policy.tpdt_init, use_ref=use_ref,
+                              interpret=interpret)
 
 
 def sweep_policies(events, n_links, t_end, tpdt_values, policy: Policy,
-                   pm: PowerModel | None = None):
+                   pm: PowerModel | None = None, interpret=False):
     """Fast sweep of fixed t_PDT values over one recorded baseline run."""
     pm = pm or PowerModel()
     gaps, durs, tail = events_to_streams(events, n_links, t_end)
-    return {t: evaluate_fixed(gaps, durs, tail, t, policy, pm)
+    return {t: evaluate_fixed(gaps, durs, tail, t, policy, pm,
+                              interpret=interpret)
             for t in tpdt_values}

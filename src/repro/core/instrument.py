@@ -1,7 +1,8 @@
 """Lightweight JAX instrumentation: compile counting for benches + tests.
 
-``count_compiles()`` taps ``jax.monitoring`` for backend-compile events so
-the benchmark driver can report how many XLA programs a run built (the
+``count_compiles()`` taps ``jax.monitoring`` for backend-compile events
+(and persistent-cache hits) so the benchmark driver and ``chip_smoke.py``
+can report how many XLA programs a run built and how long that took (the
 perf-trajectory JSON in ``benchmarks/run.py``) and the test-suite can
 assert that warm plan replays compile NOTHING.  ``compile_guard()`` turns
 that assertion into a hard runtime error for regions that MUST stay
@@ -16,27 +17,50 @@ import contextlib
 import jax.monitoring
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_state = {"installed": False, "n": 0}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_state = {"installed": False, "n": 0, "secs": 0.0, "hits": 0}
 
 
-def _on_event(event, duration, **_kw):
+def _on_duration(event, duration, **_kw):
     if event == _COMPILE_EVENT:
         _state["n"] += 1
+        _state["secs"] += duration
+
+
+def _on_event(event, **_kw):
+    if event == _CACHE_HIT_EVENT:
+        _state["hits"] += 1
 
 
 def _install():
     if not _state["installed"]:
-        jax.monitoring.register_event_duration_secs_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
         _state["installed"] = True
 
 
 class CompileCount:
-    def __init__(self, start):
-        self._start = start
+    def __init__(self):
+        self._start = dict(_state)
+
+    def _delta(self, key):
+        return _state[key] - self._start[key]
 
     @property
     def count(self) -> int:
-        return _state["n"] - self._start
+        """Programs that went through the backend compile step: built by
+        XLA, or read back from the persistent compilation cache."""
+        return self._delta("n")
+
+    @property
+    def seconds(self) -> float:
+        """Wall seconds spent in that step (compiling or cache reads)."""
+        return self._delta("secs")
+
+    @property
+    def cache_hits(self) -> int:
+        """Of ``count``, the programs read from the persistent cache."""
+        return self._delta("hits")
 
 
 @contextlib.contextmanager
@@ -45,10 +69,10 @@ def count_compiles():
 
         with count_compiles() as cc:
             ...
-        print(cc.count)
+        print(cc.count, cc.seconds, cc.cache_hits)
     """
     _install()
-    yield CompileCount(_state["n"])
+    yield CompileCount()
 
 
 class CompileGuardError(RuntimeError):

@@ -257,8 +257,17 @@ def l_factor(hops, bound):
 
 
 def _suffix_sum(x):
-    """Suffix (tail) accumulation along the bin axis."""
-    return jnp.cumsum(x[..., ::-1], axis=-1)[..., ::-1]
+    """Suffix (tail) accumulation along the bin axis.
+
+    On the TPU a cumsum lowers to an O(B^2) reduce-window, whose emulated
+    f64 expansion takes minutes to compile per replay program; a log-depth
+    associative scan compiles in seconds.  Every other backend keeps the
+    cumsum, so CPU results are bit-for-bit what they were."""
+    return lax.platform_dependent(
+        x,
+        tpu=lambda v: lax.associative_scan(lax.add, v, axis=v.ndim - 1,
+                                           reverse=True),
+        default=lambda v: jnp.cumsum(v[..., ::-1], axis=-1)[..., ::-1])
 
 
 def tpdt_select(counts, sums, N, total, policy, params=None, ccum=None):

@@ -143,8 +143,10 @@ def _row_chain(rows):
     conflict structure the chained wavefront executor needs, at O(K log K)
     instead of the O(K^2) pairwise conflict matrix."""
     K = rows.shape[0]
-    ordi = jnp.argsort(rows, stable=True)
-    inv = jnp.argsort(ordi)
+    # int32 slot indices (x64 would make them int64): the same values,
+    # and the TPU compiles the chained wave loop in half the time
+    ordi = jnp.argsort(rows, stable=True).astype(jnp.int32)
+    inv = jnp.argsort(ordi).astype(jnp.int32)
     r_s = rows[ordi]
     same_prev = jnp.concatenate(
         [jnp.zeros((1,), bool), r_s[1:] == r_s[:-1]])
@@ -238,10 +240,10 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
             # when every predecessor message has executed.  Same-message
             # predecessors (a route revisiting a link) are masked out —
             # hops of one message share a wave by definition.
-            own = jnp.arange(cap)[:, None]
+            own = jnp.arange(cap, dtype=jnp.int32)[:, None]
             pmm = (predL // H).reshape(cap, H)
-            hp = (predL != jnp.arange(K)).reshape(cap, H) & active \
-                & (pmm != own)
+            hp = (predL != jnp.arange(K, dtype=jnp.int32)).reshape(cap, H) \
+                & active & (pmm != own)
         else:
             pmm = jnp.zeros((cap, H), predL.dtype)
             hp = jnp.zeros((cap, H), bool)

@@ -37,7 +37,7 @@ import numpy as np
 from jax import lax
 
 from repro.core import perfbound as pb
-from repro.core.eee import Policy, PowerModel
+from repro.core.eee import Policy, PowerModel, policy_params
 from repro.traffic.plan import compile_plan, pad_message_table
 
 MAX_HOPS = 5
@@ -383,9 +383,10 @@ def chain_spec(policy: Policy):
 def _compiled_chunk(policy: Policy, pm: PowerModel, n_links: int,
                     collect_events: bool):
     @partial(jax.jit, donate_argnums=(0,))
-    def run(net, msgs):
+    def run(net, msgs, params):
         def step(net, m):
-            net, (d, lat, ev) = _message_step(net, m, policy, pm, n_links)
+            net, (d, lat, ev) = _message_step(net, m, policy, pm, n_links,
+                                              params=params)
             out = (d, lat, ev) if collect_events else (d, lat)
             return net, out
         return lax.scan(step, net, msgs)
@@ -393,8 +394,15 @@ def _compiled_chunk(policy: Policy, pm: PowerModel, n_links: int,
 
 
 def sim_chunk(net, msgs, policy, pm, n_links, collect_events=False):
-    """msgs: tuple of arrays (links (M,5), dirs, nhops, t_inj, bytes, valid)."""
-    return _compiled_chunk(policy, pm, n_links, collect_events)(net, msgs)
+    """msgs: tuple of arrays (links (M,5), dirs, nhops, t_inj, bytes, valid).
+
+    The policy's numerics enter as traced operands, as in the compiled
+    replay: baked in as constants, XLA would rewrite ``gap / bin_width``
+    into a multiply by the rounded reciprocal and bin a gap on a bin edge
+    one bin lower than the replay does."""
+    params = {k: jnp.float64(v) for k, v in policy_params(policy).items()}
+    return _compiled_chunk(policy, pm, n_links, collect_events)(
+        net, msgs, params)
 
 
 # ---------------------------------------------------------------------------

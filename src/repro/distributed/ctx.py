@@ -13,22 +13,16 @@ from jax.sharding import PartitionSpec as P
 
 def _mesh():
     # abstract mesh (jax.set_mesh / use_mesh context)
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
+    m = jax.sharding.get_abstract_mesh()
+    if m.axis_names:
+        return m
     # `with mesh:` (the launcher/dry-run convention) sets the physical
     # mesh on thread_resources, NOT the abstract mesh — check it too,
     # else every activation constraint in model code silently no-ops
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty and m.axis_names:
-            return m
-    except Exception:
-        pass
+    from jax._src import mesh as mesh_lib
+    m = mesh_lib.thread_resources.env.physical_mesh
+    if not m.empty and m.axis_names:
+        return m
     return None
 
 

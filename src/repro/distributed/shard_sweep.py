@@ -48,7 +48,6 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import replay
@@ -186,15 +185,16 @@ def _sharded_runner(proto: Policy, pm: PowerModel, n_links: int, cap: int,
                     needs_sort: bool = True):
     """``_make_run`` vmapped over T and shard_mapped over the mesh — the
     same scan program as ``replay._multi_segment_runner``, tiled.  Every
-    input/output is tile-local (``check_rep=False``: there is no
+    input/output is tile-local (``check_vma=False``: there is no
     replication to verify and no collective in the program).  Carry
     buffers donate, exactly like the single-device runners."""
     run = replay._make_run(proto, pm, n_links, cap, collect_events=False,
                            mode=mode, needs_sort=needs_sort)
     vrun = jax.vmap(run, in_axes=(0, None, 0, 0, 0, 0, 0))
-    sm = shard_map(vrun, mesh=mesh,
-                   in_specs=(SP_TB, SP_B, SP_TB, SP_TB, SP_TB, SP_T, SP_T),
-                   out_specs=(SP_TB, None), check_rep=False)
+    sm = jax.shard_map(vrun, mesh=mesh,
+                       in_specs=(SP_TB, SP_B, SP_TB, SP_TB, SP_TB, SP_T,
+                                 SP_T),
+                       out_specs=(SP_TB, None), check_vma=False)
     return partial(jax.jit, donate_argnums=(0, 2, 3, 4))(sm)
 
 

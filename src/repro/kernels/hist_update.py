@@ -1,11 +1,15 @@
 """Pallas TPU kernel: batched inactivity-histogram build.
 
-Ports along lanes (TILE_P=128), events along the sequential grid-free fori
-axis; each step one-hot-accumulates a (TILE_P, B) update.  Inputs arrive
-transposed (E, P) so the per-event row read is a natural (TILE_P,) vector.
+Layout: histogram bins on sublanes, ports on lanes, so the kernel builds
+the transposed (B, P) histogram.  Each event row of the (E, P) input is a
+(1, TILE_P) lane vector; its bin ids compare against a (Bp, 1)-broadcast
+sublane iota to give the (Bp, TILE_P) one-hot update directly, with no
+relayout.  The grid tiles ports (TILE_P=128 lanes, "parallel") and events
+(TILE_E rows, "arbitrary"); a fori loop walks the rows of each event block
+and accumulates into the resident output blocks.
 
-VMEM per block: gaps (E x 128 f32) + two (128 x B) accumulators:
-2048*128*4 + 2*128*256*4 = 1.3 MB.
+VMEM: gaps block (512 x 128 f32, double buffered) + two (Bp x 128)
+accumulators: 2*512*128*4 + 2*256*128*4 = 0.8 MB at B=256.
 """
 from __future__ import annotations
 
@@ -14,59 +18,77 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE_P = 128
-LANE = 128
-MAX_E = 8192
+# block-index constant: a Python 0 would be int64 under x64, which
+# Mosaic's index maps cannot return
+_ZERO = np.int32(0)
+TILE_E = 512
+SUBLANE = 8
 
 
 def _kernel(gaps_ref, counts_ref, sums_ref, *, n_bins, bin_width, log_bins,
-            log_min, log_max, n_events):
-    E = gaps_ref.shape[0]
-    Bp = counts_ref.shape[1]
-    lane_b = lax.broadcasted_iota(jnp.int32, (1, Bp), 1)
+            log_min, log_max):
+    # every constant is an explicit 32-bit scalar: under x64 a Python
+    # scalar would enter the kernel as f64/i64, which Mosaic cannot lower
+    f32, i32 = jnp.float32, jnp.int32
+    te = gaps_ref.shape[0]
+    bins = lax.broadcasted_iota(jnp.int32, counts_ref.shape, 0)
+    lo, hi = i32(0), i32(n_bins - 1)
 
-    def body(e, carry):
-        acc_c, acc_s = carry
-        g = gaps_ref[e, :]                          # (TILE_P,)
-        valid = g > 0
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        counts_ref[...] = jnp.zeros_like(counts_ref)
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    def body(r, carry):
+        g = gaps_ref[pl.ds(r, 1), :]                # (1, TILE_P)
+        valid = g > f32(0)
         if log_bins:
-            lo, hi = math.log(log_min), math.log(log_max)
-            x = (jnp.log(jnp.maximum(g, log_min)) - lo) / (hi - lo)
-            b = jnp.clip((x * n_bins).astype(jnp.int32), 0, n_bins - 1)
+            lmin, lmax = math.log(log_min), math.log(log_max)
+            x = (jnp.log(jnp.maximum(g, f32(log_min))) - f32(lmin)) \
+                / f32(lmax - lmin)
+            b = (x * f32(n_bins)).astype(jnp.int32)
         else:
-            b = jnp.clip((g / bin_width).astype(jnp.int32), 0, n_bins - 1)
-        oh = (lane_b == b[:, None]) & valid[:, None]
-        ohf = oh.astype(jnp.float32)
-        return acc_c + ohf, acc_s + ohf * jnp.where(valid, g, 0.0)[:, None]
+            b = (g / f32(bin_width)).astype(jnp.int32)
+        b = jnp.minimum(jnp.maximum(b, lo), hi)
+        oh = ((bins == b) & valid).astype(jnp.float32)
+        counts_ref[...] += oh
+        sums_ref[...] += oh * jnp.where(valid, g, f32(0))
+        return carry
 
-    z = jnp.zeros((gaps_ref.shape[1], Bp), jnp.float32)
-    acc_c, acc_s = lax.fori_loop(0, n_events, body, (z, z))
-    counts_ref[...] = acc_c
-    sums_ref[...] = acc_s
+    lax.fori_loop(i32(0), i32(te), body, i32(0))
 
 
 def hist_update_pallas(gaps, *, n_bins, bin_width, log_bins=False,
                        log_min=1e-7, log_max=10.0, interpret=False):
     """gaps: (E, P) f32.  Returns (counts (P,B), sums (P,B))."""
     E, P = gaps.shape
-    assert E <= MAX_E, f"E={E} exceeds kernel cap; chunk at ops level"
     Pp = pl.cdiv(P, TILE_P) * TILE_P
-    Bp = pl.cdiv(n_bins, LANE) * LANE
-    g = jnp.zeros((E, Pp), jnp.float32).at[:, :P].set(gaps.astype(jnp.float32))
+    Bp = pl.cdiv(n_bins, SUBLANE) * SUBLANE
+    te = min(TILE_E, pl.cdiv(E, SUBLANE) * SUBLANE)
+    Ep = pl.cdiv(E, te) * te
+    # padded events are gaps of 0: not valid, they add nothing
+    g = jnp.zeros((Ep, Pp), jnp.float32).at[:E, :P].set(
+        gaps.astype(jnp.float32))
 
     counts, sums = pl.pallas_call(
         functools.partial(_kernel, n_bins=n_bins, bin_width=float(bin_width),
                           log_bins=bool(log_bins), log_min=float(log_min),
-                          log_max=float(log_max), n_events=E),
-        grid=(Pp // TILE_P,),
-        in_specs=[pl.BlockSpec((E, TILE_P), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((TILE_P, Bp), lambda i: (i, 0)),
-                   pl.BlockSpec((TILE_P, Bp), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((Pp, Bp), jnp.float32),
-                   jax.ShapeDtypeStruct((Pp, Bp), jnp.float32)],
+                          log_max=float(log_max)),
+        grid=(Pp // TILE_P, Ep // te),
+        in_specs=[pl.BlockSpec((te, TILE_P), lambda i, e: (e, i))],
+        out_specs=[pl.BlockSpec((Bp, TILE_P), lambda i, e: (_ZERO, i)),
+                   pl.BlockSpec((Bp, TILE_P), lambda i, e: (_ZERO, i))],
+        out_shape=[jax.ShapeDtypeStruct((Bp, Pp), jnp.float32),
+                   jax.ShapeDtypeStruct((Bp, Pp), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="hist_update",
     )(g)
-    return counts[:P, :n_bins], sums[:P, :n_bins]
+    return counts[:n_bins, :P].T, sums[:n_bins, :P].T

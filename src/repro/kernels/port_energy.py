@@ -14,8 +14,15 @@ remainder at the row-2 floor.  ``t_dst = inf`` (the single-state lowering)
 keeps every row-2 select on its row-1 value, so classic policies are
 bit-identical to the pre-ladder kernel.
 
-Ports along lanes (TILE_P=128); events along a fori loop over rows of the
-transposed (E, P) input.  VMEM: gaps+durs (E x 128 f32) * 2 = 2 MB at E=2048.
+With the per-port t_PDT fixed, every event's contribution depends only on
+its own (gap, duration) pair, so the replay is a reduction over events.
+Layout: events on sublanes, ports on lanes.  The grid tiles ports
+(TILE_P=128 lanes, "parallel") and events (TILE_E rows, "arbitrary"); each
+step reduces a (TILE_E, TILE_P) block over its rows into an (8, TILE_P)
+output block that stays resident across the event axis.  The four per-port
+operands travel as rows of one (8, P) array, so every block is aligned to
+the (8, 128) f32 tile.  VMEM: gaps+durs 2 x 512 x 128 f32, double
+buffered = 1 MB.
 """
 from __future__ import annotations
 
@@ -23,65 +30,83 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE_P = 128
-MAX_E = 8192
+# block-index constant: a Python 0 would be int64 under x64, which
+# Mosaic's index maps cannot return
+_ZERO = np.int32(0)
+TILE_E = 512
+ROWS = 8
+# per-port operand rows of the (8, P) input and result rows of the output
+TPDT, TDS, HOLD, TAIL = range(4)
+KEYS = ("time_wake", "time_sleep", "time_sleep2", "n_wake", "hits",
+        "misses", "n_deep")
 
 
-def _kernel(gaps_ref, durs_ref, tpdt_ref, tds_ref, hold_ref, tail_ref,
-            wake_ref, sleep_ref, sleep2_ref, nwake_ref, hits_ref, miss_ref,
-            ndeep_ref, *, t_w, t_s, t_w2, t_s2, n_events):
-    tpdt = tpdt_ref[...]
+def _kernel(gaps_ref, durs_ref, port_ref, out_ref, *, t_w, t_s, t_w2, t_s2):
+    # every constant is an explicit f32 scalar: under x64 a Python float
+    # would enter the kernel as f64, which Mosaic cannot lower
+    f32 = jnp.float32
+    t_w, t_s, t_w2, t_s2 = f32(t_w), f32(t_s), f32(t_w2), f32(t_s2)
+    zero = f32(0)
+    e = pl.program_id(1)
+    port = port_ref[...]
+    tpdt = port[TPDT:TPDT + 1]                      # (1, TILE_P)
     # per-port demotion timer, pre-clamped to >= t_s by the caller
     # (demotion cannot precede the first down transition)
-    tds = tds_ref[...]
+    tds = port[TDS:TDS + 1]
     # predictive row: hold-at-source deferral granted to frames that find
     # the port asleep — the effective gap stretches by ``hold`` (0 = off)
-    hold = hold_ref[...]
+    hold = port[HOLD:HOLD + 1]
+    g = gaps_ref[...]                               # (TILE_E, TILE_P)
+    d = durs_ref[...]
+    act = d > zero
+    asleep = act & (g >= tpdt)
+    ge = g + jnp.where(asleep, hold, zero)
+    deep = act & (ge >= tpdt + tds)
+    wake_fast = tpdt + t_s + t_w + d
+    wake_deep = tpdt + t_s + t_s2 + t_w2 + d
+    wake_add = jnp.where(asleep, jnp.where(deep, wake_deep, wake_fast),
+                         g + d)
+    sleep_add = jnp.where(asleep,
+                          jnp.where(deep, tds - t_s,
+                                    jnp.maximum(ge - tpdt - t_s, zero)),
+                          zero)
+    sleep2_add = jnp.where(deep, jnp.maximum(ge - tpdt - tds - t_s2, zero),
+                           zero)
+    af = asleep.astype(jnp.float32)
 
-    def body(e, carry):
-        wake, sleep, sleep2, nw, hit, miss, nd = carry
-        g = gaps_ref[e, :]
-        d = durs_ref[e, :]
-        act = d > 0
-        asleep = act & (g >= tpdt)
-        ge = g + jnp.where(asleep, hold, 0.0)
-        deep = act & (ge >= tpdt + tds)
-        wake_fast = tpdt + t_s + t_w + d
-        wake_deep = tpdt + t_s + t_s2 + t_w2 + d
-        wake_add = jnp.where(asleep,
-                             jnp.where(deep, wake_deep, wake_fast), g + d)
-        sleep_add = jnp.where(asleep,
-                              jnp.where(deep, tds - t_s,
-                                        jnp.maximum(ge - tpdt - t_s, 0.0)),
-                              0.0)
-        sleep2_add = jnp.where(
-            deep, jnp.maximum(ge - tpdt - tds - t_s2, 0.0), 0.0)
-        af = asleep.astype(jnp.float32)
-        return (wake + jnp.where(act, wake_add, 0.0),
-                sleep + jnp.where(act, sleep_add, 0.0),
-                sleep2 + sleep2_add,
-                nw + af, hit + (act & ~asleep).astype(jnp.float32), miss + af,
-                nd + deep.astype(jnp.float32))
+    def rsum(x):
+        return jnp.sum(x, axis=0, keepdims=True)
 
-    z = jnp.zeros((gaps_ref.shape[1],), jnp.float32)
-    wake, sleep, sleep2, nw, hit, miss, nd = lax.fori_loop(
-        0, n_events, body, (z, z, z, z, z, z, z))
-    tail = tail_ref[...]
-    tail_sleeps = tail >= tpdt + t_s
-    tail_deep = tail >= tpdt + tds + t_s2
-    wake_ref[...] = wake + jnp.where(
-        tail_sleeps, tpdt + t_s + jnp.where(tail_deep, t_s2, 0.0), tail)
-    sleep_ref[...] = sleep + jnp.where(
-        tail_sleeps, jnp.where(tail_deep, tds - t_s, tail - tpdt - t_s), 0.0)
-    sleep2_ref[...] = sleep2 + jnp.where(
-        tail_deep, tail - tpdt - tds - t_s2, 0.0)
-    nwake_ref[...] = nw
-    hits_ref[...] = hit
-    miss_ref[...] = miss
-    ndeep_ref[...] = nd
+    adds = (rsum(jnp.where(act, wake_add, zero)),
+            rsum(jnp.where(act, sleep_add, zero)),
+            rsum(sleep2_add), rsum(af),
+            rsum((act & ~asleep).astype(jnp.float32)), rsum(af),
+            rsum(deep.astype(jnp.float32)))
+
+    @pl.when(e == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    for k, v in enumerate(adds):
+        out_ref[k:k + 1, :] += v
+
+    @pl.when(e == pl.num_programs(1) - 1)
+    def _():
+        tail = port[TAIL:TAIL + 1]
+        tail_sleeps = tail >= tpdt + t_s
+        tail_deep = tail >= tpdt + tds + t_s2
+        out_ref[0:1, :] += jnp.where(
+            tail_sleeps, tpdt + t_s + jnp.where(tail_deep, t_s2, zero), tail)
+        out_ref[1:2, :] += jnp.where(
+            tail_sleeps, jnp.where(tail_deep, tds - t_s, tail - tpdt - t_s),
+            zero)
+        out_ref[2:3, :] += jnp.where(tail_deep, tail - tpdt - tds - t_s2,
+                                     zero)
 
 
 def port_energy_pallas(gaps, durs, tpdt, tail, *, t_w, t_s,
@@ -93,39 +118,39 @@ def port_energy_pallas(gaps, durs, tpdt, tail, *, t_w, t_s,
     deferral (the precoalesce row; traced, None/0 = off).
     Returns dict of (P,)."""
     E, P = gaps.shape
-    assert E <= MAX_E, f"E={E} exceeds kernel cap; chunk at ops level"
     Pp = pl.cdiv(P, TILE_P) * TILE_P
+    te = min(TILE_E, pl.cdiv(E, ROWS) * ROWS)
+    Ep = pl.cdiv(E, te) * te
     if t_dst is None:
         t_dst = jnp.inf
     if hold is None:
         hold = 0.0
-    tds = jnp.broadcast_to(
-        jnp.maximum(jnp.asarray(t_dst, jnp.float32), jnp.float32(t_s)), (P,))
-    hld = jnp.broadcast_to(jnp.asarray(hold, jnp.float32), (P,))
+    tds = jnp.maximum(jnp.asarray(t_dst, jnp.float32), jnp.float32(t_s))
 
     def padE(x):
-        return jnp.zeros((E, Pp), jnp.float32).at[:, :P].set(
+        # padded rows carry duration 0: inactive, they add nothing
+        return jnp.zeros((Ep, Pp), jnp.float32).at[:E, :P].set(
             x.astype(jnp.float32))
 
-    def padP(x, fill=0.0):
-        return jnp.full((Pp,), fill, jnp.float32).at[:P].set(
-            x.astype(jnp.float32))
+    # padded ports never sleep (tpdt 1e30) and never demote (tds inf)
+    port = jnp.zeros((ROWS, Pp), jnp.float32)
+    port = port.at[TPDT].set(1e30).at[TDS].set(jnp.inf)
+    for row, v in ((TPDT, tpdt), (TDS, tds), (HOLD, hold), (TAIL, tail)):
+        port = port.at[row, :P].set(
+            jnp.broadcast_to(jnp.asarray(v, jnp.float32), (P,)))
 
-    outs = pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, t_w=float(t_w), t_s=float(t_s),
-                          t_w2=float(t_w2), t_s2=float(t_s2), n_events=E),
-        grid=(Pp // TILE_P,),
-        in_specs=[pl.BlockSpec((E, TILE_P), lambda i: (0, i)),
-                  pl.BlockSpec((E, TILE_P), lambda i: (0, i)),
-                  pl.BlockSpec((TILE_P,), lambda i: (i,)),
-                  pl.BlockSpec((TILE_P,), lambda i: (i,)),
-                  pl.BlockSpec((TILE_P,), lambda i: (i,)),
-                  pl.BlockSpec((TILE_P,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((TILE_P,), lambda i: (i,))] * 7,
-        out_shape=[jax.ShapeDtypeStruct((Pp,), jnp.float32)] * 7,
+                          t_w2=float(t_w2), t_s2=float(t_s2)),
+        grid=(Pp // TILE_P, Ep // te),
+        in_specs=[pl.BlockSpec((te, TILE_P), lambda i, e: (e, i)),
+                  pl.BlockSpec((te, TILE_P), lambda i, e: (e, i)),
+                  pl.BlockSpec((ROWS, TILE_P), lambda i, e: (_ZERO, i))],
+        out_specs=pl.BlockSpec((ROWS, TILE_P), lambda i, e: (_ZERO, i)),
+        out_shape=jax.ShapeDtypeStruct((ROWS, Pp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(padE(gaps), padE(durs), padP(tpdt, fill=1e30),
-      padP(tds, fill=float("inf")), padP(hld), padP(tail))
-    keys = ["time_wake", "time_sleep", "time_sleep2", "n_wake", "hits",
-            "misses", "n_deep"]
-    return {k: v[:P] for k, v in zip(keys, outs)}
+        name="port_energy",
+    )(padE(gaps), padE(durs), port)
+    return {k: out[i, :P] for i, k in enumerate(KEYS)}

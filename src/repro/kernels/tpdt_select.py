@@ -1,12 +1,16 @@
 """Pallas TPU kernel: PerfBound t_PDT bin selection over all ports at once.
 
-Layout: ports tiled over the grid (TP rows/block), bins along lanes (B padded
-to a lane multiple).  The reverse cumulative sum is computed as a matmul with
-a lower-triangular ones matrix — MXU-friendly, no sequential scan — then the
-leftmost feasible bin is selected with a one-hot reduction.
+Layout: bins on sublanes (B padded to a multiple of 8), ports on lanes
+(TILE_P=128 per grid step), so every per-port quantity is a (1, TILE_P)
+lane row.  The reverse cumulative sum over bins is a matmul with an
+upper-triangular ones matrix — MXU-friendly, no sequential scan — and the
+leftmost feasible bin is found with a float min-reduction over sublanes
+and selected with a one-hot reduction.  The per-port operands N/total
+travel as rows of one (8, P) array and the bin centers as a lane-broadcast
+(Bp, 128) block, so every block is aligned to the (8, 128) f32 tile.
 
-VMEM per block: counts/sums (TP x B f32) + the (B x B) triangular matrix:
-128*256*4 * 2 + 256*256*4 = 518 KB.
+VMEM per block: counts/sums (Bp x 128 f32) + the (Bp x Bp) triangular
+matrix: 2*256*128*4 + 256*256*4 = 512 KB at B=256.
 """
 from __future__ import annotations
 
@@ -14,70 +18,86 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE_P = 128
-LANE = 128
+# block-index constant: a Python 0 would be int64 under x64, which
+# Mosaic's index maps cannot return
+_ZERO = np.int32(0)
+SUBLANE = 8
+ROWS = 8
+N_ROW, TOTAL_ROW = 0, 1
 
 
-def _kernel(counts_ref, sums_ref, n_ref, total_ref, centers_ref, tpdt_ref, *,
+def _kernel(counts_ref, sums_ref, port_ref, centers_ref, tpdt_ref, *,
             n_bins, max_tpdt, tpdt_init):
-    c = counts_ref[...]                       # (TP, Bp)
+    # every constant is an explicit f32 scalar: under x64 a Python float
+    # would enter the kernel as f64, which Mosaic cannot lower
+    f32 = jnp.float32
+    c = counts_ref[...]                       # (Bp, TP)
     s = sums_ref[...]
-    N = n_ref[...]                            # (TP,)
-    total = total_ref[...]
-    centers = centers_ref[...]                # (Bp,)
-    Bp = c.shape[-1]
+    N = port_ref[N_ROW:N_ROW + 1, :]          # (1, TP)
+    total = port_ref[TOTAL_ROW:TOTAL_ROW + 1, :]
+    centers = centers_ref[...]                # (Bp, TP), lane-broadcast
+    Bp = c.shape[0]
 
-    # reverse cumsum via triangular matmul: rcum[:, j] = sum_{i>=j} c[:, i]
+    # reverse cumsum via triangular matmul: rcum[j, :] = sum_{i>=j} c[i, :]
     row = lax.broadcasted_iota(jnp.int32, (Bp, Bp), 0)
     col = lax.broadcasted_iota(jnp.int32, (Bp, Bp), 1)
-    tri = (row >= col).astype(jnp.float32)
-    rcum = jnp.dot(c, tri, preferred_element_type=jnp.float32)
+    tri = (col >= row).astype(jnp.float32)
+    rcum = jnp.dot(tri, c, preferred_element_type=jnp.float32,
+                   precision=lax.Precision.HIGHEST)
 
-    lane = lax.broadcasted_iota(jnp.int32, (1, Bp), 1)
-    feas = (rcum <= N[:, None]) & (lane < n_bins)
-    found = feas.any(axis=1)
-    j = jnp.argmax(feas, axis=1)
-    oh = (lane == j[:, None]).astype(jnp.float32)
-    cj = (c * oh).sum(axis=1)
-    sj = (s * oh).sum(axis=1)
-    ctr = (centers[None, :] * oh).sum(axis=1)
-    mean = jnp.where(cj > 0, sj / jnp.maximum(cj, 1e-30), ctr)
-    t = jnp.where(found, mean, max_tpdt)
-    tpdt_ref[...] = jnp.where(total > 0, t, tpdt_init)
+    binf = lax.broadcasted_iota(jnp.int32, c.shape, 0).astype(jnp.float32)
+    feas = (rcum <= N) & (binf < f32(n_bins))
+    j = jnp.min(jnp.where(feas, binf, f32(Bp)), axis=0, keepdims=True)
+    found = j < f32(Bp)
+    oh = (binf == j).astype(jnp.float32)
+    cj = jnp.sum(c * oh, axis=0, keepdims=True)
+    sj = jnp.sum(s * oh, axis=0, keepdims=True)
+    ctr = jnp.sum(centers * oh, axis=0, keepdims=True)
+    mean = jnp.where(cj > f32(0), sj / jnp.maximum(cj, f32(1e-30)), ctr)
+    t = jnp.where(found, mean, f32(max_tpdt))
+    t = jnp.where(total > f32(0), t, f32(tpdt_init))
+    tpdt_ref[...] = jnp.broadcast_to(t, tpdt_ref.shape)
 
 
 def tpdt_select_pallas(counts, sums, N, total, centers, *, max_tpdt,
                        tpdt_init, interpret=False):
+    """counts/sums: (P, B) f32; N/total: (P,); centers: (B,).
+    Returns the selected t_PDT, (P,) f32."""
     P, B = counts.shape
     Pp = pl.cdiv(P, TILE_P) * TILE_P
-    Bp = pl.cdiv(B, LANE) * LANE
+    Bp = pl.cdiv(B, SUBLANE) * SUBLANE
 
-    def pad(x, shape):
-        return jnp.zeros(shape, x.dtype).at[tuple(slice(0, d)
-                                                  for d in x.shape)].set(x)
+    def binsT(x):
+        return jnp.zeros((Bp, Pp), jnp.float32).at[:B, :P].set(
+            x.astype(jnp.float32).T)
 
-    counts = pad(counts.astype(jnp.float32), (Pp, Bp))
-    sums = pad(sums.astype(jnp.float32), (Pp, Bp))
-    N = pad(N.astype(jnp.float32), (Pp,))
-    total = pad(total.astype(jnp.float32), (Pp,))
-    centers = pad(centers.astype(jnp.float32), (Bp,))
+    port = jnp.zeros((ROWS, Pp), jnp.float32)
+    port = port.at[N_ROW, :P].set(N.astype(jnp.float32))
+    port = port.at[TOTAL_ROW, :P].set(total.astype(jnp.float32))
+    ctr = jnp.zeros((Bp, TILE_P), jnp.float32).at[:B].set(
+        jnp.broadcast_to(centers.astype(jnp.float32)[:, None], (B, TILE_P)))
 
     out = pl.pallas_call(
         functools.partial(_kernel, n_bins=B, max_tpdt=float(max_tpdt),
                           tpdt_init=float(tpdt_init)),
         grid=(Pp // TILE_P,),
         in_specs=[
-            pl.BlockSpec((TILE_P, Bp), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_P, Bp), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_P,), lambda i: (i,)),
-            pl.BlockSpec((TILE_P,), lambda i: (i,)),
-            pl.BlockSpec((Bp,), lambda i: (0,)),
+            pl.BlockSpec((Bp, TILE_P), lambda i: (_ZERO, i)),
+            pl.BlockSpec((Bp, TILE_P), lambda i: (_ZERO, i)),
+            pl.BlockSpec((ROWS, TILE_P), lambda i: (_ZERO, i)),
+            pl.BlockSpec((Bp, TILE_P), lambda i: (_ZERO, _ZERO)),
         ],
-        out_specs=pl.BlockSpec((TILE_P,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Pp,), jnp.float32),
+        out_specs=pl.BlockSpec((ROWS, TILE_P), lambda i: (_ZERO, i)),
+        out_shape=jax.ShapeDtypeStruct((ROWS, Pp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(counts, sums, N, total, centers)
-    return out[:P]
+        name="tpdt_select",
+    )(binsT(counts), binsT(sums), port, ctr)
+    return out[0, :P]

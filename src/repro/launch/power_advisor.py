@@ -302,6 +302,8 @@ def main():
     ap.add_argument("--steps", type=int, default=3)
     ap.add_argument("--max-overhead-pct", type=float, default=1.0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     modes = [m for m in (args.arch, args.scenario, args.stream)
              if m is not None]
     if len(modes) != 1:

@@ -64,7 +64,8 @@ def test_decoupled_matches_coupled_hit_miss_counts(topo, pm):
         coupled, _ = S.simulate_trace(tr, topo, pol, pm)
         gaps, durs, tail = D.events_to_streams(events, topo.n_links,
                                                res0.makespan)
-        dec = D.evaluate_fixed(gaps, durs, tail, t_pdt, pol, pm)
+        dec = D.evaluate_fixed(gaps, durs, tail, t_pdt, pol, pm,
+                               interpret=True)
         n_wake_dec = float(np.asarray(dec["n_wake"]).sum())
         # counts agree within 15 % (feedback shifts borderline gaps)
         if coupled.n_wake_transitions:
@@ -82,7 +83,8 @@ def test_sweep_policies_monotone_energy(topo, pm):
     res0, events = _events(topo, pm, tr)
     pol = Policy(kind="fixed", sleep_state="deep_sleep")
     sweep = D.sweep_policies(events, topo.n_links, res0.makespan,
-                             [0.0, 1e-5, 1e-4, 1e-3, 1e-2, 1.0], pol, pm)
+                             [0.0, 1e-5, 1e-4, 1e-3, 1e-2, 1.0], pol, pm,
+                             interpret=True)
     keys = sorted(sweep)
     wake = [sweep[t]["wake_time"] for t in keys]
     # monotone up to the transition-overhead slack: raising t_PDT past a gap
@@ -109,14 +111,14 @@ def test_perfbound_snapshot_prediction(topo, pm):
     pol = Policy(kind="perfbound", bound=0.01, hist_bin_width=10e-6,
                  max_tpdt=10e-3, sleep_state="deep_sleep")
     t = D.perfbound_snapshot_tpdt(gaps, t_elapsed=20.0, hop_mean=3.0,
-                                  policy=pol)
+                                  policy=pol, interpret=True)
     t = np.asarray(t)
     # budget N = 0.01/3 * 20 / 4.48e-6 ~ 1.5e4 >> 420 samples: everything is
     # affordable -> t_PDT lands at/below the short mode (aggressive)
     assert (t <= 1e-4).all()
     # a tight window (X small) forces conservative prediction
     t2 = np.asarray(D.perfbound_snapshot_tpdt(
-        gaps, t_elapsed=1e-3, hop_mean=3.0, policy=pol))
+        gaps, t_elapsed=1e-3, hop_mean=3.0, policy=pol, interpret=True))
     assert (t2 >= t).all()
 
 
@@ -126,7 +128,7 @@ def test_ref_and_kernel_paths_agree_end_to_end(topo, pm):
     gaps, durs, tail = D.events_to_streams(events, topo.n_links,
                                            res0.makespan)
     pol = Policy(kind="fixed", sleep_state="fast_wake")
-    a = D.evaluate_fixed(gaps, durs, tail, 1e-4, pol, pm, use_ref=False)
+    a = D.evaluate_fixed(gaps, durs, tail, 1e-4, pol, pm, interpret=True)
     b = D.evaluate_fixed(gaps, durs, tail, 1e-4, pol, pm, use_ref=True)
     np.testing.assert_allclose(a["link_energy"], b["link_energy"],
                                rtol=1e-5)
@@ -149,7 +151,8 @@ def test_decoupled_dual_mode_ladder(topo, pm):
                   sleep_state="fast_wake", deep_state="deep_sleep")
     out = {}
     for name, pol in (("fw", fw), ("ds", ds), ("dual", dual)):
-        a = D.evaluate_fixed(gaps, durs, tail, t_pdt, pol, pm, use_ref=False)
+        a = D.evaluate_fixed(gaps, durs, tail, t_pdt, pol, pm,
+                             interpret=True)
         b = D.evaluate_fixed(gaps, durs, tail, t_pdt, pol, pm, use_ref=True)
         for k in ("link_energy", "wake_time", "sleep_time", "sleep2_time"):
             np.testing.assert_allclose(a[k], b[k], rtol=1e-5, atol=1e-8,

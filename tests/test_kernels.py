@@ -1,5 +1,8 @@
-"""Per-kernel validation: Pallas (interpret mode on CPU) vs ref.py oracle,
-swept over shapes, plus hypothesis property tests on kernel invariants."""
+"""Per-kernel validation: Pallas (interpret mode, requested explicitly) vs
+the ref.py oracle, swept over shapes, plus hypothesis property tests on
+kernel invariants.  A compiled kernel call off the TPU must raise."""
+from functools import partial
+
 import hypothesis.extra.numpy as hnp
 import hypothesis.strategies as st
 import jax
@@ -11,6 +14,12 @@ from hypothesis import given, settings
 from repro.core import perfbound as pb
 from repro.core.eee import Policy
 from repro.kernels import ops, ref
+
+# The CPU suite runs the decoupled evaluator's kernels in interpret mode,
+# asked for explicitly; ``use_ref=True`` still selects the jnp oracle.
+tpdt_select_op = partial(ops.tpdt_select_op, interpret=True)
+hist_update_op = partial(ops.hist_update_op, interpret=True)
+port_energy_op = partial(ops.port_energy_op, interpret=True)
 
 SHAPE_SWEEP_P = [1, 3, 64, 128, 130, 257]
 SHAPE_SWEEP_B = [8, 100, 128, 200, 256]
@@ -37,7 +46,7 @@ def _rand_hist(rng, P, B):
 def test_tpdt_select_matches_ref(P, B, rng):
     counts, sums, N, total, centers = _rand_hist(rng, P, B)
     kw = dict(max_tpdt=10e-3, tpdt_init=1e-3)
-    got = ops.tpdt_select_op(counts, sums, N, total, centers, **kw)
+    got = tpdt_select_op(counts, sums, N, total, centers, **kw)
     want = ref.tpdt_select_ref(
         *(jnp.asarray(a, jnp.float32)
           for a in (counts, sums, N, total, centers)), **kw)
@@ -49,11 +58,11 @@ def test_tpdt_select_matches_ref(P, B, rng):
 def test_tpdt_select_dtypes(dtype, rng):
     counts, sums, N, total, centers = _rand_hist(rng, 64, 200)
     kw = dict(max_tpdt=10e-3, tpdt_init=1e-3)
-    got = ops.tpdt_select_op(counts.astype(dtype), sums.astype(dtype),
-                             N.astype(dtype), total.astype(dtype),
-                             centers.astype(dtype), **kw)
-    want = ops.tpdt_select_op(counts, sums, N, total, centers, use_ref=True,
-                              **kw)
+    got = tpdt_select_op(counts.astype(dtype), sums.astype(dtype),
+                         N.astype(dtype), total.astype(dtype),
+                         centers.astype(dtype), **kw)
+    want = tpdt_select_op(counts, sums, N, total, centers, use_ref=True,
+                          **kw)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
 
@@ -66,8 +75,8 @@ def test_tpdt_select_empty_history():
     centers = (np.arange(B) + 0.5).astype(np.float32)
     N = np.zeros((2,), np.float32)
     total = counts.sum(1)
-    out = np.asarray(ops.tpdt_select_op(counts, sums, N, total, centers,
-                                        max_tpdt=7.0, tpdt_init=3.0))
+    out = np.asarray(tpdt_select_op(counts, sums, N, total, centers,
+                                    max_tpdt=7.0, tpdt_init=3.0))
     assert out[0] == 3.0      # no history
     assert out[1] == 7.0      # feasible nowhere (tail count 50 > N=0)
 
@@ -76,8 +85,8 @@ def test_tpdt_select_leftmost_feasible(rng):
     """The oracle picks the LEFTMOST bin whose tail accumulation <= N, and
     t_PDT is that bin's mean — cross-checked against a python loop."""
     counts, sums, N, total, centers = _rand_hist(rng, 32, 64)
-    out = np.asarray(ops.tpdt_select_op(counts, sums, N, total, centers,
-                                        max_tpdt=99.0, tpdt_init=-1.0))
+    out = np.asarray(tpdt_select_op(counts, sums, N, total, centers,
+                                    max_tpdt=99.0, tpdt_init=-1.0))
     for p in range(32):
         rcum = np.cumsum(counts[p][::-1])[::-1]
         feas = np.nonzero(rcum <= N[p])[0]
@@ -107,8 +116,8 @@ def test_tpdt_select_property(data):
     centers = (np.arange(B) + 0.5).astype(np.float32)
     sums = counts * centers[None]
     total = counts.sum(1)
-    out = np.asarray(ops.tpdt_select_op(counts, sums, N, total, centers,
-                                        max_tpdt=1e6, tpdt_init=0.5))
+    out = np.asarray(tpdt_select_op(counts, sums, N, total, centers,
+                                    max_tpdt=1e6, tpdt_init=0.5))
     rcum = np.cumsum(counts[:, ::-1], 1)[:, ::-1]
     feasible = (rcum <= N[:, None]).any(1)
     has_hist = total > 0
@@ -126,14 +135,15 @@ def test_tpdt_select_property(data):
 
 
 @pytest.mark.parametrize("E,P", [(1, 1), (7, 3), (64, 128), (100, 130),
+                                 (1100, 130),
                                  (513, 64)])
 @pytest.mark.parametrize("log_bins", [False, True])
 def test_hist_update_matches_ref(E, P, log_bins, rng):
     gaps = rng.uniform(-1e-5, 5e-3, (E, P)).astype(np.float32)
     kw = dict(n_bins=200, bin_width=10e-6, log_bins=log_bins,
               log_min=1e-7, log_max=1.0)
-    gc, gs = ops.hist_update_op(gaps, **kw)
-    wc, ws = ops.hist_update_op(gaps, use_ref=True, **kw)
+    gc, gs = hist_update_op(gaps, **kw)
+    wc, ws = hist_update_op(gaps, use_ref=True, **kw)
     np.testing.assert_allclose(np.asarray(gc), np.asarray(wc), atol=0)
     np.testing.assert_allclose(np.asarray(gs), np.asarray(ws),
                                rtol=1e-5, atol=1e-9)
@@ -149,7 +159,7 @@ def test_hist_update_conservation(data):
         np.float32, (E, P),
         elements=st.floats(-0.0009765625, 0.0078125, allow_nan=False,
                            allow_subnormal=False, width=32)))
-    counts, sums = ops.hist_update_op(gaps, n_bins=128, bin_width=1e-4)
+    counts, sums = hist_update_op(gaps, n_bins=128, bin_width=1e-4)
     valid = gaps > 0
     np.testing.assert_allclose(np.asarray(counts).sum(1), valid.sum(0))
     np.testing.assert_allclose(np.asarray(sums).sum(1),
@@ -162,7 +172,7 @@ def test_hist_update_agrees_with_perfbound_binning():
     pol = Policy(kind="perfbound", hist_bins=50, hist_bin_width=1e-4)
     gaps = np.array([[5e-5, 1.23e-4, 4.9e-3, 1e9]], np.float32).T  # (4,1)->
     gaps = gaps.reshape(4, 1)
-    counts, _ = ops.hist_update_op(gaps, n_bins=50, bin_width=1e-4)
+    counts, _ = hist_update_op(gaps, n_bins=50, bin_width=1e-4)
     want_bins = np.asarray(pb.bin_index(jnp.asarray(gaps[:, 0]), pol))
     got_nonzero = np.nonzero(np.asarray(counts)[0])[0]
     assert sorted(set(want_bins.tolist())) == sorted(got_nonzero.tolist())
@@ -173,7 +183,8 @@ def test_hist_update_agrees_with_perfbound_binning():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("E,P", [(1, 1), (16, 64), (100, 128), (257, 130)])
+@pytest.mark.parametrize("E,P", [(1, 1), (16, 64), (100, 128), (257, 130),
+                                 (1100, 130)])
 def test_port_energy_matches_ref(E, P, rng):
     gaps = rng.uniform(0, 2e-3, (E, P)).astype(np.float32)
     durs = rng.uniform(0, 1e-4, (E, P)).astype(np.float32)
@@ -181,8 +192,8 @@ def test_port_energy_matches_ref(E, P, rng):
     tpdt = rng.uniform(0, 1e-3, (P,)).astype(np.float32)
     tail = rng.uniform(0, 1.0, (P,)).astype(np.float32)
     kw = dict(t_w=4.48e-6, t_s=2e-6)
-    got = ops.port_energy_op(gaps, durs, tpdt, tail, **kw)
-    want = ops.port_energy_op(gaps, durs, tpdt, tail, use_ref=True, **kw)
+    got = port_energy_op(gaps, durs, tpdt, tail, **kw)
+    want = port_energy_op(gaps, durs, tpdt, tail, use_ref=True, **kw)
     for k in got:
         np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
                                    rtol=1e-5, atol=1e-8, err_msg=k)
@@ -204,7 +215,7 @@ def test_port_energy_conservation(data):
     tpdt = data.draw(hnp.arrays(np.float32, (P,),
                                 elements=st.floats(0, 0.0078125, width=32)))
     t_w, t_s = 4.48e-6, 2e-6
-    out = ops.port_energy_op(gaps, durs, tpdt, tail, t_w=t_w, t_s=t_s)
+    out = port_energy_op(gaps, durs, tpdt, tail, t_w=t_w, t_s=t_s)
     span = gaps.sum(0) + durs.sum(0) + tail
     total = np.asarray(out["time_wake"]) + np.asarray(out["time_sleep"])
     # Every second of the stream is accounted at exactly one power level,
@@ -225,11 +236,11 @@ def test_port_energy_extremes():
     gaps = np.full((4, 2), 1e-3, np.float32)
     durs = np.full((4, 2), 1e-5, np.float32)
     tail = np.full((2,), 0.1, np.float32)
-    always = ops.port_energy_op(gaps, durs, np.zeros(2, np.float32), tail,
-                                t_w=4.48e-6, t_s=2e-6)
-    never = ops.port_energy_op(gaps, durs,
-                               np.full((2,), 1e9, np.float32), tail,
-                               t_w=4.48e-6, t_s=2e-6)
+    always = port_energy_op(gaps, durs, np.zeros(2, np.float32), tail,
+                            t_w=4.48e-6, t_s=2e-6)
+    never = port_energy_op(gaps, durs,
+                           np.full((2,), 1e9, np.float32), tail,
+                           t_w=4.48e-6, t_s=2e-6)
     assert (np.asarray(always["n_wake"]) == 4).all()
     assert (np.asarray(never["n_wake"]) == 0).all()
     assert (np.asarray(never["time_sleep"]) == 0).all()
@@ -251,10 +262,10 @@ def test_port_energy_hold_matches_ref(E, P, rng):
     tail = rng.uniform(0, 1.0, (P,)).astype(np.float32)
     hold = rng.uniform(0, 5e-4, (P,)).astype(np.float32)
     kw = dict(t_w=4.48e-6, t_s=2e-6, t_w2=1e-4, t_s2=1e-5)
-    got = ops.port_energy_op(gaps, durs, tpdt, tail, t_dst=2e-4, hold=hold,
-                             **kw)
-    want = ops.port_energy_op(gaps, durs, tpdt, tail, t_dst=2e-4, hold=hold,
-                              use_ref=True, **kw)
+    got = port_energy_op(gaps, durs, tpdt, tail, t_dst=2e-4, hold=hold,
+                         **kw)
+    want = port_energy_op(gaps, durs, tpdt, tail, t_dst=2e-4, hold=hold,
+                          use_ref=True, **kw)
     for k in got:
         np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
                                    rtol=1e-5, atol=1e-8, err_msg=k)
@@ -269,8 +280,8 @@ def test_port_energy_hold_zero_is_identity(rng):
     tpdt = rng.uniform(0, 1e-3, (64,)).astype(np.float32)
     tail = rng.uniform(0, 1.0, (64,)).astype(np.float32)
     kw = dict(t_w=4.48e-6, t_s=2e-6, t_w2=1e-4, t_s2=1e-5, t_dst=2e-4)
-    off = ops.port_energy_op(gaps, durs, tpdt, tail, **kw)
-    zero = ops.port_energy_op(gaps, durs, tpdt, tail, hold=0.0, **kw)
+    off = port_energy_op(gaps, durs, tpdt, tail, **kw)
+    zero = port_energy_op(gaps, durs, tpdt, tail, hold=0.0, **kw)
     for k in off:
         np.testing.assert_array_equal(np.asarray(off[k]),
                                       np.asarray(zero[k]), err_msg=k)
@@ -286,8 +297,8 @@ def test_port_energy_hold_stretches_gap_into_deep():
     tpdt = np.full((2,), 1e-4, np.float32)
     tail = np.zeros((2,), np.float32)
     kw = dict(t_w=4.48e-6, t_s=2e-6, t_w2=1e-4, t_s2=1e-5, t_dst=t_dst)
-    off = ops.port_energy_op(gaps, durs, tpdt, tail, hold=0.0, **kw)
-    on = ops.port_energy_op(gaps, durs, tpdt, tail, hold=t_dst, **kw)
+    off = port_energy_op(gaps, durs, tpdt, tail, hold=0.0, **kw)
+    on = port_energy_op(gaps, durs, tpdt, tail, hold=t_dst, **kw)
     # port 0 never slept: the hold row must not touch it
     assert np.asarray(off["n_deep"])[0] == np.asarray(on["n_deep"])[0] == 0
     np.testing.assert_array_equal(np.asarray(off["time_wake"])[0],
@@ -296,6 +307,40 @@ def test_port_energy_hold_stretches_gap_into_deep():
     assert np.asarray(off["n_deep"])[1] == 0
     assert np.asarray(on["n_deep"])[1] == 1
     assert np.asarray(on["time_sleep2"])[1] > 0
+
+
+# ---------------------------------------------------------------------------
+# interpret mode is the caller's choice
+# ---------------------------------------------------------------------------
+
+
+def _kernel_calls():
+    gaps = np.full((4, 3), 1e-4, np.float32)
+    counts = np.ones((3, 16), np.float32)
+    vec = np.ones((3,), np.float32)
+    centers = np.arange(16, dtype=np.float32)
+    return {
+        "tpdt_select": lambda **k: ops.tpdt_select_op(
+            counts, counts, vec, vec, centers, max_tpdt=1.0, tpdt_init=0.5,
+            **k),
+        "hist_update": lambda **k: ops.hist_update_op(
+            gaps, n_bins=16, bin_width=1e-5, **k),
+        "port_energy": lambda **k: ops.port_energy_op(
+            gaps, gaps, vec, vec, t_w=4.48e-6, t_s=2e-6, **k),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["tpdt_select", "hist_update",
+                                    "port_energy"])
+def test_compiled_kernel_off_tpu_raises(kernel):
+    """Off the TPU a kernel call without interpret=True raises: nothing
+    drops to interpret mode unasked."""
+    assert jax.default_backend() != "tpu"
+    call = _kernel_calls()[kernel]
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        call()
+    jax.block_until_ready(call(interpret=True))
+    jax.block_until_ready(call(use_ref=True))
 
 
 # ---------------------------------------------------------------------------

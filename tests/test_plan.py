@@ -106,6 +106,50 @@ def test_compiled_replay_matches_step_loop(topo_name, kind, data):
     _assert_results_match(got, want, f"{topo_name}/{kind}")
 
 
+def _pinned_trace(nodes, steps):
+    tr = Trace(nodes=np.asarray(nodes, np.int64), name="pinned")
+    for op, arg in steps:
+        if op == "compute":
+            tr.compute(np.asarray(arg))
+        else:
+            tr.messages(arg, barrier=op == "msgs_barrier")
+    return tr
+
+
+# Draws that once split the two engines for the adaptive kinds: a gap of
+# exactly one histogram bin width (1e-5 s), which the step loop binned one
+# bin lower while its policy numerics were compile-time constants.
+PINNED_TRACES = {
+    "selfmsg-after-compute": ([0, 1], [
+        ("compute", [1e-5, 1.953125e-3]), ("msgs", [[0, 0, 64]]),
+        ("msgs_barrier", [[0, 1, 1024]])]),
+    "selfmsg-before-compute": ([0, 1], [
+        ("msgs", [[0, 0, 64]]), ("compute", [1e-5, 1.953125e-3]),
+        ("msgs_barrier", [[0, 1, 1024]])]),
+    "fan-in-then-compute": ([0, 1, 2, 4], [
+        ("msgs", [[1, 0, 64], [4, 0, 64], [0, 0, 64]]),
+        ("compute", [1e-5, 1.953125e-3, 1.953125e-3, 1.953125e-3]),
+        ("msgs_barrier", [[0, 4, 1024]])]),
+    "one-short-node": (list(range(8)), [
+        ("compute", [1.953125e-3] * 7 + [1e-5]), ("msgs", [[7, 0, 64]]),
+        ("msgs_barrier", [[0, 7, 1024]])]),
+}
+
+
+@pytest.mark.parametrize("topo_name", list(TOPOS))
+@pytest.mark.parametrize("kind", ["perfbound", "perfbound_correct",
+                                  "perfbound_dual", "predict"])
+@pytest.mark.parametrize("trace_name", list(PINNED_TRACES))
+def test_compiled_replay_matches_step_loop_pinned(topo_name, kind,
+                                                  trace_name):
+    topo = TOPOS[topo_name]
+    tr = _pinned_trace(*PINNED_TRACES[trace_name])
+    pol = POLICIES[kind]
+    want, _ = S.simulate_trace_reference(tr, topo, pol, PM)
+    got, _ = S.simulate_trace(tr, topo, pol, PM)
+    _assert_results_match(got, want, f"{topo_name}/{kind}/{trace_name}")
+
+
 @pytest.mark.parametrize("topo_name", list(TOPOS))
 @settings(max_examples=3, deadline=None)
 @given(data=st.data())

@@ -15,20 +15,8 @@ from repro.training.loop import abstract_train_state
 
 
 def _abstract_mesh(shape, names):
-    """An abstract mesh with fake sizes (no devices needed for spec tests).
-
-    jax has changed this constructor across releases: <=0.4.35 had no
-    AbstractMesh, 0.4.36/0.4.37 take ``((name, size), ...)`` pairs, and
-    >=0.5 takes ``(shape, names)`` like Mesh.  Probe the pair form first.
-    """
-    AbstractMesh = getattr(jax.sharding, "AbstractMesh", None)
-    if AbstractMesh is None:  # module-level: _abstract_mesh runs at import
-        pytest.skip("jax.sharding.AbstractMesh unavailable in this jax",
-                    allow_module_level=True)
-    try:
-        return AbstractMesh(tuple(zip(names, shape)))
-    except TypeError:
-        return AbstractMesh(shape, names)
+    """An abstract mesh with fake sizes (no devices needed for spec tests)."""
+    return jax.sharding.AbstractMesh(shape, names)
 
 
 MESH = _abstract_mesh((16, 16), ("data", "model"))
