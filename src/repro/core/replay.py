@@ -39,6 +39,7 @@ from jax import lax
 from repro.core import simulator as S
 from repro.core.eee import (PARAM_FIELDS, Policy, PowerModel,
                             canonical_proto, policy_params)
+from repro.core.instrument import add, span
 
 
 def stack_params(pols) -> dict:
@@ -497,6 +498,9 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
 
         return lax.scan(step, (nets, ready, lat_sum, lat_max), xs)
 
+    # the compiled program (and its device trace events) reads
+    # ``jit_replay_<mode>``: the executor, whichever runner jits it
+    run.__name__ = run.__qualname__ = f"replay_{mode}"
     return run
 
 
@@ -657,13 +661,17 @@ def replay_plans(batch, pols, pm):
     bit-identical to that trace's own single-trace ``replay_plan`` —
     the multi runner is the same program vmapped over T.
     """
-    proto, params, carry = init_lanes_multi(pols, batch)
-    nets, t_end, lat_sum, lat_max = run_segments_multi(
-        batch, proto, params, pm, carry)
-    t_end = np.asarray(t_end)
+    with span("repro.replay.dispatch", kind=pols[0].kind, lanes=len(pols)):
+        proto, params, carry = init_lanes_multi(pols, batch)
+        nets, t_end, lat_sum, lat_max = run_segments_multi(
+            batch, proto, params, pm, carry)
+    with span("repro.replay.wait"):
+        t_end = np.asarray(t_end)
+    lat_sum, lat_max = np.asarray(lat_sum), np.asarray(lat_max)
+    add("readback_bytes", t_end.nbytes + lat_sum.nbytes + lat_max.nbytes)
     # traces with no participants have an all-False mask row (-inf max)
     t_end = np.where(batch.has_participants[:, None], t_end, 0.0)
-    return nets, t_end, np.asarray(lat_sum), np.asarray(lat_max)
+    return nets, t_end, lat_sum, lat_max
 
 
 def events_to_host(plan, seg_events):

@@ -39,6 +39,7 @@ import numpy as np
 from repro.core import replay
 from repro.core import simulator as S
 from repro.core.eee import PowerModel, static_key
+from repro.core.instrument import add, span
 from repro.core.replay import stack_params  # noqa: F401 (public re-export)
 from repro.traffic.plan import (compile_plan, group_stackable,
                                 stack_plans_cached)
@@ -155,12 +156,14 @@ def sweep_cells(traces: dict, topo, cells: dict,
             first = next(c[pn] for c in cells.values() if pn in c)
             assert pol == first, \
                 f"policy {pn!r} differs across traces (one name, one column)"
-    plans = [compile_plan(traces[n], topo) for n in tnames]
+    with span("repro.plan.lower"):
+        plans = [compile_plan(traces[n], topo) for n in tnames]
     out: dict = {n: {} for n in tnames}
     for idx in group_stackable(plans):
-        batch = stack_plans_cached([plans[i] for i in idx],
-                                   [tnames[i] for i in idx],
-                                   packing=packing)
+        with span("repro.plan.stack"):
+            batch = stack_plans_cached([plans[i] for i in idx],
+                                       [tnames[i] for i in idx],
+                                       packing=packing)
         union: dict = {}
         for gi in idx:
             union.update(cells[tnames[gi]])
@@ -179,18 +182,23 @@ def sweep_cells(traces: dict, topo, cells: dict,
                         batch, pols, pm)
                 # one readback for the whole (T, B) grid: per-cell host
                 # numpy views, not one tiny sliced device program per cell
-                nets = jax.tree.map(np.asarray, nets)
-                for ti, gi in enumerate(idx):
-                    want = cells[tnames[gi]]
-                    for b, pname in enumerate(chunk):
-                        if pname not in want:
-                            continue
-                        net_tb = jax.tree.map(lambda x: x[ti, b], nets)
-                        out[tnames[gi]][pname] = S.summarize(
-                            net_tb, float(t_end[ti, b]),
-                            float(batch.busy[ti]),
-                            float(lat_sum[ti, b]), float(lat_max[ti, b]),
-                            int(batch.n_msgs[ti]), pols[b], pm, topo)
+                with span("repro.sweep.readback"):
+                    nets = jax.tree.map(np.asarray, nets)
+                add("readback_bytes",
+                    sum(x.nbytes for x in jax.tree.leaves(nets)))
+                with span("repro.sweep.summarize"):
+                    for ti, gi in enumerate(idx):
+                        want = cells[tnames[gi]]
+                        for b, pname in enumerate(chunk):
+                            if pname not in want:
+                                continue
+                            net_tb = jax.tree.map(lambda x: x[ti, b], nets)
+                            out[tnames[gi]][pname] = S.summarize(
+                                net_tb, float(t_end[ti, b]),
+                                float(batch.busy[ti]),
+                                float(lat_sum[ti, b]),
+                                float(lat_max[ti, b]),
+                                int(batch.n_msgs[ti]), pols[b], pm, topo)
     return {tn: {pn: out[tn][pn] for pn in cells[tn]} for tn in cells}
 
 

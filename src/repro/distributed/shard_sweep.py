@@ -52,6 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import replay
 from repro.core.eee import Policy, PowerModel, canonical_proto
+from repro.core.instrument import add, span
 from repro.traffic.plan import PlanBatch
 
 SP_TB = P("trace", "lane")
@@ -221,30 +222,34 @@ def replay_plans_sharded(batch: PlanBatch, pols, pm: PowerModel,
     T_pad = math.ceil(T / dt) * dt
     B_pad = math.ceil(B / db) * db
 
-    proto = canonical_proto(pols[0])
-    params = replay.stack_params(_pad_pols(pols, B_pad))
-    carry = replay._multi_init(proto, batch.n_links, batch.n_nodes,
-                               T_pad)(params)
+    with span("repro.replay.dispatch", kind=pols[0].kind, lanes=len(pols)):
+        proto = canonical_proto(pols[0])
+        params = replay.stack_params(_pad_pols(pols, B_pad))
+        carry = replay._multi_init(proto, batch.n_links, batch.n_nodes,
+                                   T_pad)(params)
 
-    def put(x, spec):
-        return jax.device_put(x, NamedSharding(mesh, spec))
+        def put(x, spec):
+            return jax.device_put(x, NamedSharding(mesh, spec))
 
-    params = jax.tree.map(lambda x: put(x, SP_B), params)
-    carry = (jax.tree.map(lambda x: put(x, SP_TB), carry[0]),
-             put(carry[1], SP_TB), put(carry[2], SP_TB),
-             put(carry[3], SP_TB))
-    part_mask, seg_xs = _place_batch(batch, mesh, T_pad)
+        params = jax.tree.map(lambda x: put(x, SP_B), params)
+        carry = (jax.tree.map(lambda x: put(x, SP_TB), carry[0]),
+                 put(carry[1], SP_TB), put(carry[2], SP_TB),
+                 put(carry[3], SP_TB))
+        part_mask, seg_xs = _place_batch(batch, mesh, T_pad)
 
-    for seg, xs in zip(batch.segments, seg_xs):
-        md, ns = replay._seg_flags(seg, proto)
-        run = _sharded_runner(proto, pm, batch.n_links, seg.cap, mesh,
-                              md, ns)
-        carry, _ = run(carry[0], params, carry[1], carry[2], carry[3],
-                       part_mask, xs)
-    nets, ready, lat_sum, lat_max = carry
+        for seg, xs in zip(batch.segments, seg_xs):
+            md, ns = replay._seg_flags(seg, proto)
+            run = _sharded_runner(proto, pm, batch.n_links, seg.cap, mesh,
+                                  md, ns)
+            carry, _ = run(carry[0], params, carry[1], carry[2], carry[3],
+                           part_mask, xs)
+        nets, ready, lat_sum, lat_max = carry
+        t_end = replay._participant_max_multi(part_mask, ready)
 
-    t_end = np.asarray(replay._participant_max_multi(part_mask, ready))
+    with span("repro.replay.wait"):
+        t_end = np.asarray(t_end)
+    lat_sum, lat_max = np.asarray(lat_sum), np.asarray(lat_max)
+    add("readback_bytes", t_end.nbytes + lat_sum.nbytes + lat_max.nbytes)
     t_end = np.where(batch.has_participants[:, None], t_end[:T, :B], 0.0)
     nets = jax.tree.map(lambda x: x[:T, :B], nets)
-    return (nets, t_end, np.asarray(lat_sum)[:T, :B],
-            np.asarray(lat_max)[:T, :B])
+    return nets, t_end, lat_sum[:T, :B], lat_max[:T, :B]

@@ -219,19 +219,25 @@ def advise_stream(drift: str, *, budget_pct: float = 0.1,
 
 
 def print_stream_report(out: dict) -> None:
-    """Render an ``advise_stream`` report as the CLI/experiment table."""
+    """Render an ``advise_stream`` report as the CLI/experiment table.
+
+    ``re-advice ms`` is the window's host time summed over its layers
+    (``host_ms``); ``slowest`` names the layer that took most of it."""
     print(f"stream: {out['stream']} ({out['drift']}, "
           f"{out['windows']} windows)  budget <= "
           f"{out['budget_pct']:g}% overhead  objective={out['objective']}")
     print(f"pool: {', '.join(out['pool'])}")
     print(f"  {'w':>3s} {'rate/s':>8s} {'incumbent':28s} {'ovh%':>7s} "
-          f"{'saved%':>7s} {'compiles':>8s}  switch")
+          f"{'saved%':>7s} {'compiles':>8s} {'re-advice ms':>12s} "
+          f"{'slowest':9s}  switch")
     for r in out["timeline"]:
         sw = f"-> {r['next_incumbent']} ({r['reason']})" \
             if r["switched"] else ""
+        host = r["host_ms"]
         print(f"  {r['window']:3d} {r['rate']:8.0f} {r['incumbent']:28s} "
               f"{r['overhead_pct']:7.3f} {r['saved_pct']:7.2f} "
-              f"{r['compiles']:8d}  {sw}")
+              f"{r['compiles']:8d} {sum(host.values()):12.1f} "
+              f"{max(host, key=host.get):9s}  {sw}")
     t = out["totals"]
     print(f"switches: {out['switches']}   final incumbent: "
           f"{out['final_incumbent']}")

@@ -29,7 +29,8 @@ import contextlib
 from typing import Dict, Optional
 
 from repro.core.eee import Policy, PowerModel
-from repro.core.instrument import compile_guard, count_compiles
+from repro.core.instrument import (compile_guard, count_compiles, span,
+                                   span_log)
 from repro.core.replay import wavefront_mode
 from repro.core.simulator import relative_rows, unused_key
 from repro.core.sweep import sweep_cells
@@ -39,6 +40,19 @@ from repro.streaming.drift import DriftSpec, window_rates, window_trace
 from repro.tuning import OBJECTIVES
 
 BASELINE = "baseline"
+
+#: a re-advice's host layers: each the summed spans of one window
+HOST_LAYERS = {
+    "synth": ("repro.stream.synth",),
+    "lower": ("repro.plan.lower", "repro.plan.stack"),
+    "dispatch": ("repro.replay.dispatch",),
+    "wait": ("repro.replay.wait",),
+    "readback": ("repro.sweep.readback",),
+    "summarize": ("repro.sweep.summarize",),
+    "decide": ("repro.stream.decide",),
+}
+#: a re-advice's counters: bytes placed on the device, bytes read back
+COUNTERS = ("readback_bytes", "placed_bytes")
 
 
 def challenger_pool(topo, *, family: str = "dc", n_nodes: int = 16,
@@ -101,6 +115,14 @@ def advise_stream(spec: DriftSpec, topo, *,
       ``energy`` / ``saved_pct`` vs the window's own baseline, whether the
       controller ``switched`` afterwards (and why), and the window's
       backend-compile count (0 after window 0 — the warm-path contract);
+      ``host_ms`` — the re-advice's host milliseconds by layer
+      (``HOST_LAYERS``: ``synth`` drift synthesis, ``lower`` plan
+      lowering and stacking, ``dispatch`` enqueueing the replays,
+      ``wait`` blocked on the device, ``readback`` net state to host,
+      ``summarize`` the per-lane statistics, ``decide`` the controller
+      and accounting), each the summed ``instrument.span`` durations of
+      that layer; and ``counts`` — ``placed_bytes`` (plan bytes put on
+      the device) and ``readback_bytes`` (bytes brought back to host);
     * ``totals`` — stream-level accounting: online energy/overhead vs the
       always-on baseline AND vs the best single static policy in
       hindsight (the lowest-total-energy pool candidate whose TOTAL
@@ -141,35 +163,44 @@ def advise_stream(spec: DriftSpec, topo, *,
                                for n in (BASELINE, *pool)}
     online = {"energy": 0.0, "makespan": 0.0}
     for w in range(spec.windows):
-        trace = window_trace(spec, topo, w)
-        guard = (compile_guard(f"stream window {w} re-advice", 0)
-                 if warm_guard and w > 0 else count_compiles())
-        with guard as cc, wavefront_mode(wavefront):
-            wname = trace.name
-            res = sweep_cells({wname: trace}, topo, {wname: lanes}, pm,
-                              packing=packing)[wname]
-        base = res.pop(base_key)
-        rows = relative_rows(base, res, BASELINE)
+        with span_log() as log, \
+                span("repro.stream.window", window=w, stream=spec.name):
+            with span("repro.stream.synth"):
+                trace = window_trace(spec, topo, w)
+            guard = (compile_guard(f"stream window {w} re-advice", 0)
+                     if warm_guard and w > 0 else count_compiles())
+            with guard as cc, wavefront_mode(wavefront):
+                wname = trace.name
+                res = sweep_cells({wname: trace}, topo, {wname: lanes}, pm,
+                                  packing=packing)[wname]
+            with span("repro.stream.decide"):
+                base = res.pop(base_key)
+                rows = relative_rows(base, res, BASELINE)
 
-        served = state.incumbent             # chosen before seeing window w
-        for name in totals:
-            totals[name]["energy"] += rows[name][objective]
-            totals[name]["makespan"] += rows[name]["makespan"]
-        online["energy"] += rows[served][objective]
-        online["makespan"] += rows[served]["makespan"]
+                served = state.incumbent     # chosen before seeing window w
+                for name in totals:
+                    totals[name]["energy"] += rows[name][objective]
+                    totals[name]["makespan"] += rows[name]["makespan"]
+                online["energy"] += rows[served][objective]
+                online["makespan"] += rows[served]["makespan"]
 
-        state, switched, reason = decide(
-            state, _window_scores(rows, objective), cfg)
-        timeline.append({
-            "window": w, "rate": float(rates[w]), "incumbent": served,
-            "overhead_pct": rows[served]["exec_overhead_pct"],
-            "energy": rows[served][objective],
-            "saved_pct": 100 * (1 - rows[served][objective]
-                                / rows[BASELINE][objective])
-            if rows[BASELINE][objective] else 0.0,
-            "switched": switched, "reason": reason,
-            "next_incumbent": state.incumbent, "compiles": cc.count,
-        })
+                state, switched, reason = decide(
+                    state, _window_scores(rows, objective), cfg)
+                row = {
+                    "window": w, "rate": float(rates[w]),
+                    "incumbent": served,
+                    "overhead_pct": rows[served]["exec_overhead_pct"],
+                    "energy": rows[served][objective],
+                    "saved_pct": 100 * (1 - rows[served][objective]
+                                        / rows[BASELINE][objective])
+                    if rows[BASELINE][objective] else 0.0,
+                    "switched": switched, "reason": reason,
+                    "next_incumbent": state.incumbent, "compiles": cc.count,
+                }
+                timeline.append(row)
+            row["host_ms"] = {k: log.ms(*names)
+                              for k, names in HOST_LAYERS.items()}
+            row["counts"] = {k: log.counts.get(k, 0) for k in COUNTERS}
 
     base_tot = totals[BASELINE]
     def _ovh(t):

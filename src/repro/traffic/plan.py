@@ -39,6 +39,8 @@ from typing import List, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.instrument import add
+
 BUCKET_MIN = 64        # smallest message-slot bucket (shared by all engines)
 STEP_BUCKET_MIN = 4    # smallest per-segment step-count bucket
 MAX_STEP_PAD = 32      # cap on shared-bucket padding of a short segment
@@ -506,6 +508,7 @@ def compile_plan(trace, topo, bucket_min: int = BUCKET_MIN) -> TracePlan:
     if ck not in plans:
         _CACHE_STATS["misses"] += 1
         plans[ck] = _compile(trace, topo, bucket_min)
+        add("placed_bytes", plan_nbytes(plans[ck]))
     else:
         _CACHE_STATS["hits"] += 1
     return plans[ck]

@@ -14,7 +14,7 @@ from repro.core import replay
 from repro.core import simulator as S
 from repro.core import sweep as W
 from repro.core.eee import Policy, PowerModel
-from repro.core.instrument import count_compiles
+from repro.core.instrument import count_compiles, span_log
 from repro.distributed import shard_sweep as SS
 from repro.scenarios.spec import build_trace
 from repro.scenarios.suite import resolve
@@ -109,6 +109,28 @@ def test_replay_plans_sharded_bit_identical(topo):
     for k, a, b in zip(("t_end", "lat_sum", "lat_max"), ref[1:], got[1:]):
         assert np.array_equal(a, b), k
     _assert_tree_equal(ref[0], got[0])
+
+
+@multi_device
+def test_sharded_replay_records_the_replay_layers():
+    """The sharded branch opens the same dispatch / wait spans as the
+    single-device engine and counts the padded (T, B) accumulators it
+    brings to host."""
+    import math
+    traces = _dc_traces(TINY)
+    batch = stack_plans([compile_plan(t, TINY) for t in traces.values()],
+                        list(traces))
+    pols = [Policy(kind="fixed", t_pdt=float(t)) for t in (1e-6, 1e-5)]
+    mesh = SS.mesh_for(batch.n_traces, len(pols))
+    with span_log() as log:
+        SS.replay_plans_sharded(batch, pols, PM, mesh)
+    assert [s.name for s in log.spans] == ["repro.replay.dispatch",
+                                           "repro.replay.wait"]
+    assert all(s.parent is None for s in log.spans)
+    dt, db = mesh.shape["trace"], mesh.shape["lane"]
+    cells = (math.ceil(batch.n_traces / dt) * dt
+             * math.ceil(len(pols) / db) * db)
+    assert log.counts == {"readback_bytes": 3 * 8 * cells}
 
 
 @multi_device

@@ -3,6 +3,7 @@ hysteresis-controller properties, window-replay equivalence to the serial
 simulator, the warm-path zero-compile contract, and the regret acceptance
 gate (online strictly beats the best static policy in hindsight on a
 drifting dc-* stream, within the degradation budget)."""
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -269,3 +270,115 @@ def test_advise_stream_front_door(tiny):
     out = front("drift-dc-regimes", budget_pct=0.1, topo=tiny, n_nodes=8,
                 windows=3, pool=POOL)
     assert out["stream"] == "drift-dc-regimes" and out["windows"] == 3
+
+
+# ---------------------------------------------------------------------------
+# The report's per-window host layers and counters
+# ---------------------------------------------------------------------------
+
+HOST_KEYS = {"synth", "lower", "dispatch", "wait", "readback", "summarize",
+             "decide"}
+
+
+@pytest.fixture(scope="module")
+def spied(tiny):
+    """One stream on fresh window traces, with each window's span log, the
+    arrays its replays return and the plans it lowers, window by window."""
+    import contextlib
+
+    from repro.core import replay, sweep
+    from repro.streaming import online
+    spec = get_drift("drift-dc-diurnal").scaled(n_nodes=8, windows=3,
+                                                seed=9173)
+    logs, replays, plans = [], [], []
+    real_log, real_trace = online.span_log, online.window_trace
+    real_replay, real_plan = replay.replay_plans, sweep.compile_plan
+
+    @contextlib.contextmanager
+    def span_log():
+        with real_log() as log:
+            logs.append(log)
+            yield log
+
+    def window_trace(*a):
+        replays.append([])
+        plans.append([])
+        return real_trace(*a)
+
+    def replay_plans(*a):
+        out = real_replay(*a)
+        replays[-1].append(out)
+        return out
+
+    def compile_plan(*a, **kw):
+        plans[-1].append(real_plan(*a, **kw))
+        return plans[-1][-1]
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(online, "span_log", span_log)
+        mp.setattr(online, "window_trace", window_trace)
+        mp.setattr(replay, "replay_plans", replay_plans)
+        mp.setattr(sweep, "compile_plan", compile_plan)
+        out = advise_stream(spec, tiny, pool=POOL, budget_pct=0.1, pm=PM)
+    return spec, out, logs, replays, plans
+
+
+def test_stream_rows_carry_host_ms_by_layer(spied):
+    """Every window reports its seven host layers, each >= 0, and together
+    no more than the window's own wall time (its root span)."""
+    _, out, logs, _, _ = spied
+    assert len(logs) == len(out["timeline"]) == 3
+    for row, log in zip(out["timeline"], logs):
+        assert set(row["host_ms"]) == HOST_KEYS
+        assert all(v >= 0 for v in row["host_ms"].values())
+        root = log.spans[0]
+        assert root.name == "repro.stream.window" and root.parent is None
+        assert all(s.parent is not None for s in log.spans[1:])
+        assert sum(row["host_ms"].values()) \
+            <= 1e-6 * (root.end_ns - root.start_ns)
+        assert row["host_ms"]["dispatch"] > 0 and row["host_ms"]["wait"] > 0
+    assert [r["compiles"] for r in out["timeline"][1:]] == [0, 0]
+
+
+def test_stream_counts_match_window_arrays(spied):
+    """``readback_bytes`` is the bytes of every array the window's replays
+    bring to host; ``placed_bytes`` the plan bytes its lowering placed."""
+    from repro.traffic.plan import plan_nbytes
+    _, out, _, replays, plans = spied
+    for row, outs, ps in zip(out["timeline"], replays, plans):
+        assert len(outs) == 3            # static groups: none, fixed, dual
+        want = sum(sum(np.asarray(x).nbytes for x in jax.tree.leaves(o))
+                   for o in outs)
+        assert row["counts"]["readback_bytes"] == want > 0
+        assert len(ps) == 1
+        assert row["counts"]["placed_bytes"] == plan_nbytes(ps[0]) > 0
+
+
+def test_stream_report_repeats_but_for_timing(spied, tiny):
+    """A second run on the same stream, its caches emptied, reports the
+    same numbers in every field but the host times (and no compile)."""
+    from repro.streaming.drift import window_cache_clear
+    from repro.traffic.plan import plan_cache_clear
+    spec, first, _, _, _ = spied
+    window_cache_clear()
+    plan_cache_clear()
+    again = advise_stream(spec, tiny, pool=POOL, budget_pct=0.1, pm=PM)
+
+    def fields(out):
+        return dict(out, timeline=[
+            {k: v for k, v in r.items() if k not in ("host_ms", "compiles")}
+            for r in out["timeline"]])
+    assert fields(again) == fields(first)
+    assert all(r["compiles"] == 0 for r in again["timeline"])
+
+
+def test_stream_report_prints_readvice_and_slowest_layer(spied, capsys):
+    from repro.launch.power_advisor import print_stream_report
+    _, out, _, _, _ = spied
+    print_stream_report(out)
+    lines = capsys.readouterr().out.splitlines()
+    assert "re-advice ms" in lines[2] and "slowest" in lines[2]
+    for r, line in zip(out["timeline"], lines[3:]):
+        host = r["host_ms"]
+        assert f"{sum(host.values()):12.1f}" in line
+        assert f" {max(host, key=host.get)} " in line

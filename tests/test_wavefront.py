@@ -237,6 +237,33 @@ def test_wavefront_multi_trace_grid():
             assert lat_max[ti, bi] == w["max_latency"]
 
 
+@pytest.mark.parametrize("mode", ["scan", "prefix", "chain"])
+def test_replay_programs_are_named_by_executor(mode):
+    """Each segment program is named after its executor, so a device trace
+    reads ``jit_replay_<mode>`` for it, on the single- and multi-trace
+    runners alike."""
+    topo = TOPOS["megafly"]
+    tr = Trace(nodes=np.arange(6, dtype=np.int64))
+    tr.messages([[i, (i + 1) % 6, 2048] for i in range(6)], barrier=True)
+    plan = P.compile_plan(tr, topo)
+    seg = next(s for s in plan.segments if s.cap)
+    pols = [Policy(kind="fixed", t_pdt=2e-6)]
+    proto, params, carry = replay.init_lanes(pols, plan)
+    run = replay._segment_runner(proto, PM, plan.n_links, seg.cap, False,
+                                 mode)
+    text = run.lower(carry[0], params, carry[1], carry[2], carry[3],
+                     plan.part_mask, seg.xs).as_text()
+    assert f"module @jit_replay_{mode}" in text
+    batch = P.stack_plans([plan])
+    proto, params, carry = replay.init_lanes_multi(pols, batch)
+    run = replay._multi_segment_runner(proto, PM, batch.n_links, seg.cap,
+                                       mode)
+    text = run.lower(carry[0], params, carry[1], carry[2], carry[3],
+                     batch.part_mask,
+                     next(s for s in batch.segments if s.cap).xs).as_text()
+    assert f"module @jit_replay_{mode}" in text
+
+
 # ---------------------------------------------------------------------------
 # Device residency: warm wavefront replay = 0 compiles, 0 transfers
 # ---------------------------------------------------------------------------
