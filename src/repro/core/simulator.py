@@ -410,34 +410,89 @@ def sim_chunk(net, msgs, policy, pm, n_links, collect_events=False):
 # ---------------------------------------------------------------------------
 
 
+def _close_out(net, t_end_sim, t_s, t_s2, n_links: int):
+    """``close_out`` on the row-1 and row-2 down-transition times ``t_s``
+    and ``t_s2``: plain numbers or traced operands alike."""
+    # jnp inputs throughout: raw numpy would warn on the (masked-away)
+    # inf-inf deep spans of never-woken links
+    last = jnp.asarray(net["last_end"][:n_links])
+    dl = jnp.asarray(net["deadline"][:n_links])
+    dl2 = jnp.asarray(net["deadline2"][:n_links])
+    t_end_sim = jnp.maximum(t_end_sim, last.max())
+    sleeps = dl + t_s < t_end_sim
+    deeps = dl2 + t_s2 < t_end_sim
+    # elapsed part of the second down transition (wake power, like every
+    # transition): full t_s2 once demoted, partial if the sim ends
+    # mid-transition, 0 for single-state rows (dl2 = +inf)
+    down2 = jnp.clip(t_end_sim - dl2, 0.0, t_s2)
+    wake_extra = jnp.where(
+        sleeps, (dl - last) + t_s + down2, t_end_sim - last)
+    sleep_extra = jnp.where(
+        sleeps, jnp.where(deeps, dl2 - (dl + t_s),
+                          jnp.minimum(t_end_sim, dl2) - dl - t_s), 0.0)
+    sleep2_extra = jnp.where(deeps, t_end_sim - dl2 - t_s2, 0.0)
+    return (net["time_wake"][:n_links] + jnp.maximum(wake_extra, 0.0),
+            net["time_sleep"][:n_links] + jnp.maximum(sleep_extra, 0.0),
+            net["time_sleep2"][:n_links] + jnp.maximum(sleep2_extra, 0.0))
+
+
 def close_out(net, t_end_sim, policy: Policy, n_links: int):
     """Integrate every link's tail (last transmission .. end of sim) at the
     FSM row it ends in: awake, row-1 sleep past ``deadline``, row-2 sleep
     past ``deadline2`` (never reached by single-state kinds).  Returns
     (time_wake, time_sleep, time_sleep2)."""
-    st, st2 = policy.state, policy.deep
-    # jnp inputs throughout: the multi-trace readback hands numpy views in,
-    # and raw numpy would warn on the (masked-away) inf-inf deep spans of
-    # never-woken links
-    last = jnp.asarray(net["last_end"][:n_links])
-    dl = jnp.asarray(net["deadline"][:n_links])
-    dl2 = jnp.asarray(net["deadline2"][:n_links])
-    t_end_sim = jnp.maximum(t_end_sim, last.max())
-    sleeps = dl + st.t_s < t_end_sim
-    deeps = dl2 + st2.t_s < t_end_sim
-    # elapsed part of the second down transition (wake power, like every
-    # transition): full t_s2 once demoted, partial if the sim ends
-    # mid-transition, 0 for single-state rows (dl2 = +inf)
-    down2 = jnp.clip(t_end_sim - dl2, 0.0, st2.t_s)
-    wake_extra = jnp.where(
-        sleeps, (dl - last) + st.t_s + down2, t_end_sim - last)
-    sleep_extra = jnp.where(
-        sleeps, jnp.where(deeps, dl2 - (dl + st.t_s),
-                          jnp.minimum(t_end_sim, dl2) - dl - st.t_s), 0.0)
-    sleep2_extra = jnp.where(deeps, t_end_sim - dl2 - st2.t_s, 0.0)
-    return (net["time_wake"][:n_links] + jnp.maximum(wake_extra, 0.0),
-            net["time_sleep"][:n_links] + jnp.maximum(sleep_extra, 0.0),
-            net["time_sleep2"][:n_links] + jnp.maximum(sleep2_extra, 0.0))
+    return _close_out(net, t_end_sim, policy.state.t_s, policy.deep.t_s,
+                      n_links)
+
+
+#: What ``lane_sums`` reduces each lane's net state to, in this order: the
+#: link-summed close-out times, the last transmission end, and the
+#: link-summed FSM counters (as float64, exact below 2**53).
+LANE_SUMS = ("time_wake", "time_sleep", "time_sleep2", "last_end_max",
+             "n_wake", "n_hit", "n_miss", "n_deep")
+_COUNTERS = ("n_wake", "n_hit", "n_miss", "n_deep")
+_REDUCED = ("last_end", "deadline", "deadline2", "time_wake", "time_sleep",
+            "time_sleep2") + _COUNTERS
+
+
+def _lane_sums(net, t_end, t_s, t_s2, n_links):
+    tw, ts_, ts2 = _close_out(net, t_end, t_s, t_s2, n_links)
+    return jnp.stack(
+        [tw.sum(), ts_.sum(), ts2.sum(), net["last_end"][:n_links].max()]
+        + [net[k][:n_links].sum().astype(jnp.float64) for k in _COUNTERS])
+
+
+@partial(jax.jit, static_argnames=("n_links",))
+def _reduce_lanes(net, times, n_links):
+    """One program per (lane shape, n_links): ``_lane_sums`` vmapped over
+    the lane axes of ``times``, each lane's (end time, t_s, t_s2) stacked
+    ahead of them so that a group's operands cross in one transfer."""
+    f = partial(_lane_sums, n_links=n_links)
+    for _ in range(times.ndim - 1):
+        f = jax.vmap(f)
+    return f(net, *times)
+
+
+def lane_sums(nets, t_end, pols, n_links: int) -> np.ndarray:
+    """Reduce every lane of a replay's net state to its ``LANE_SUMS`` on
+    the device and bring back that array alone: host float64 of shape
+    ``t_end.shape + (len(LANE_SUMS),)``.
+
+    ``nets`` leaves carry the lane axes of ``t_end`` (host end times:
+    ``(T, B)``, ``(B,)`` or ``()`` for one unbatched net) before the link
+    axis; ``pols`` are the B policies of the last lane axis (one for an
+    unbatched net).  Their down-transition times enter as traced operands,
+    as the replay's parameters do (see ``sim_chunk``), so every group of
+    one lane shape shares one program."""
+    t_end = np.asarray(t_end, np.float64)
+    cols = [policy_params(p) for p in pols]
+
+    def lanewise(key):
+        return np.array([c[key] for c in cols]).reshape(t_end.shape[-1:])
+    times = np.stack(np.broadcast_arrays(t_end, lanewise("t_s"),
+                                         lanewise("t_s2")))
+    return np.asarray(_reduce_lanes({k: nets[k] for k in _REDUCED}, times,
+                                    n_links))
 
 
 @dataclass
@@ -461,17 +516,19 @@ class SimResult:
         return dataclasses.asdict(self)
 
 
-def summarize(net, t_end, busy_node_secs, lat_sum, lat_max, n_msgs,
+def summarize(lane, t_end, busy_node_secs, lat_sum, lat_max, n_msgs,
               policy: Policy, pm: PowerModel, topo) -> SimResult:
-    tw, ts_, ts2 = close_out(net, t_end, policy, topo.n_links)
+    """One cell's statistics from its lane's ``LANE_SUMS`` row (from
+    ``lane_sums``) and its replay's end time, busy node-seconds and
+    latency accumulators: float64 arithmetic on the host."""
+    tw, ts_, ts2, _, n_wake, n_hit, n_miss, n_deep = (float(x) for x in lane)
     frac = policy.state.power_frac
     frac2 = policy.deep.power_frac
-    link_e = float(2 * pm.port_power
-                   * (tw.sum() + frac * ts_.sum() + frac2 * ts2.sum()))
+    link_e = 2 * pm.port_power * (tw + frac * ts_ + frac2 * ts2)
     switch_e = float(pm.switch_power * topo.n_switches * t_end)
     node_e = float(pm.node_power_min * topo.n_nodes * t_end
                    + (pm.node_power_max - pm.node_power_min) * busy_node_secs)
-    total_t = tw.sum() + ts_.sum() + ts2.sum()
+    total_t = tw + ts_ + ts2
     return SimResult(
         makespan=float(t_end),
         mean_latency=float(lat_sum / max(n_msgs, 1)),
@@ -481,13 +538,12 @@ def summarize(net, t_end, busy_node_secs, lat_sum, lat_max, n_msgs,
         switch_energy=switch_e,
         node_energy=node_e,
         total_energy=link_e + switch_e + node_e,
-        asleep_frac=float((ts_.sum() + ts2.sum())
-                          / jnp.maximum(total_t, 1e-30)),
-        deep_frac=float(ts2.sum() / jnp.maximum(total_t, 1e-30)),
-        n_wake_transitions=int(net["n_wake"][:topo.n_links].sum()),
-        hits=int(net["n_hit"][:topo.n_links].sum()),
-        misses=int(net["n_miss"][:topo.n_links].sum()),
-        deep_misses=int(net["n_deep"][:topo.n_links].sum()),
+        asleep_frac=(ts_ + ts2) / max(total_t, 1e-30),
+        deep_frac=ts2 / max(total_t, 1e-30),
+        n_wake_transitions=int(n_wake),
+        hits=int(n_hit),
+        misses=int(n_miss),
+        deep_misses=int(n_deep),
     )
 
 
@@ -522,8 +578,8 @@ def simulate_trace(trace, topo, policy: Policy, pm: PowerModel | None = None,
     plan = compile_plan(trace, topo)
     nets, t_end, lat_sum, lat_max, seg_events = replay.replay_plan(
         plan, [policy], pm, collect_events)
-    net0 = jax.tree.map(lambda x: x[0], nets)
-    res = summarize(net0, float(t_end[0]), plan.busy, float(lat_sum[0]),
+    lane = lane_sums(nets, t_end, [policy], topo.n_links)[0]
+    res = summarize(lane, float(t_end[0]), plan.busy, float(lat_sum[0]),
                     float(lat_max[0]), plan.n_msgs, policy, pm, topo)
     events = (replay.events_to_host(plan, seg_events) if collect_events
               else None)
@@ -579,8 +635,8 @@ def simulate_trace_reference(trace, topo, policy: Policy,
             ready[nodes] = ready[nodes].max()
 
     t_end = float(ready[trace.nodes].max()) if len(trace.nodes) else 0.0
-    res = summarize(net, t_end, busy, lat_sum, lat_max, n_msgs,
-                    policy, pm, topo)
+    res = summarize(lane_sums(net, t_end, [policy], topo.n_links), t_end,
+                    busy, lat_sum, lat_max, n_msgs, policy, pm, topo)
     return res, all_events
 
 

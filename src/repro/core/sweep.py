@@ -33,9 +33,6 @@ ONE batched replay of its kind's static group.
 """
 from __future__ import annotations
 
-import jax
-import numpy as np
-
 from repro.core import replay
 from repro.core import simulator as S
 from repro.core.eee import PowerModel, static_key
@@ -71,10 +68,10 @@ def _sweep_group(trace, topo, names, pols, pm):
     """Replay ``trace`` once for a static-structure group of B policies."""
     plan = compile_plan(trace, topo)
     nets, t_end, lat_sum, lat_max, _ = replay.replay_plan(plan, pols, pm)
+    lanes = S.lane_sums(nets, t_end, pols, topo.n_links)
     out = {}
     for b, name in enumerate(names):
-        net_b = jax.tree.map(lambda x: x[b], nets)
-        out[name] = S.summarize(net_b, float(t_end[b]), plan.busy,
+        out[name] = S.summarize(lanes[b], float(t_end[b]), plan.busy,
                                 float(lat_sum[b]), float(lat_max[b]),
                                 plan.n_msgs, pols[b], pm, topo)
     return out
@@ -180,21 +177,19 @@ def sweep_cells(traces: dict, topo, cells: dict,
                 else:
                     nets, t_end, lat_sum, lat_max = replay.replay_plans(
                         batch, pols, pm)
-                # one readback for the whole (T, B) grid: per-cell host
-                # numpy views, not one tiny sliced device program per cell
+                # the whole (T, B) grid reduced on the device, one small
+                # readback; the net state itself never leaves the device
                 with span("repro.sweep.readback"):
-                    nets = jax.tree.map(np.asarray, nets)
-                add("readback_bytes",
-                    sum(x.nbytes for x in jax.tree.leaves(nets)))
+                    lanes = S.lane_sums(nets, t_end, pols, topo.n_links)
+                add("readback_bytes", lanes.nbytes)
                 with span("repro.sweep.summarize"):
                     for ti, gi in enumerate(idx):
                         want = cells[tnames[gi]]
                         for b, pname in enumerate(chunk):
                             if pname not in want:
                                 continue
-                            net_tb = jax.tree.map(lambda x: x[ti, b], nets)
                             out[tnames[gi]][pname] = S.summarize(
-                                net_tb, float(t_end[ti, b]),
+                                lanes[ti, b], float(t_end[ti, b]),
                                 float(batch.busy[ti]),
                                 float(lat_sum[ti, b]),
                                 float(lat_max[ti, b]),

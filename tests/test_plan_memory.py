@@ -108,8 +108,7 @@ def test_repack_bit_identical_to_pow2_and_serial():
         want, _ = S.simulate_trace(tr, TINY, pol, PM)
         plan = repack_plans([compile_plan(tr, TINY)])[0]
         nets, t_end, ls, lm, _ = replay.replay_plan(plan, [pol], PM)
-        import jax
-        got = S.summarize(jax.tree.map(lambda x: x[0], nets),
+        got = S.summarize(S.lane_sums(nets, t_end, [pol], TINY.n_links)[0],
                           float(t_end[0]), plan.busy, float(ls[0]),
                           float(lm[0]), plan.n_msgs, pol, PM, TINY)
         assert got.as_dict() == want.as_dict()

@@ -3,7 +3,6 @@ hysteresis-controller properties, window-replay equivalence to the serial
 simulator, the warm-path zero-compile contract, and the regret acceptance
 gate (online strictly beats the best static policy in hindsight on a
 drifting dc-* stream, within the degradation budget)."""
-import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -342,13 +341,15 @@ def test_stream_rows_carry_host_ms_by_layer(spied):
 
 def test_stream_counts_match_window_arrays(spied):
     """``readback_bytes`` is the bytes of every array the window's replays
-    bring to host; ``placed_bytes`` the plan bytes its lowering placed."""
+    bring to host: each lane's end time, latency sums and ``LANE_SUMS``
+    row, never its net state; ``placed_bytes`` the plan bytes its lowering
+    placed."""
     from repro.traffic.plan import plan_nbytes
     _, out, _, replays, plans = spied
     for row, outs, ps in zip(out["timeline"], replays, plans):
         assert len(outs) == 3            # static groups: none, fixed, dual
-        want = sum(sum(np.asarray(x).nbytes for x in jax.tree.leaves(o))
-                   for o in outs)
+        # per lane: t_end, lat_sum, lat_max and its LANE_SUMS, float64
+        want = sum(8 * o[1].size * (3 + len(S.LANE_SUMS)) for o in outs)
         assert row["counts"]["readback_bytes"] == want > 0
         assert len(ps) == 1
         assert row["counts"]["placed_bytes"] == plan_nbytes(ps[0]) > 0

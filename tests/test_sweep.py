@@ -356,6 +356,69 @@ def test_close_out_accounts_full_makespan(topo, pm, name):
     assert (over <= bound).all(), "overshoot beyond transition extensions"
 
 
+# ---------------------------------------------------------------------------
+# Lane reduction on the device (simulator.lane_sums) against numpy float64
+# ---------------------------------------------------------------------------
+
+
+def _np_lane_sums(net, t_end, pol, n):
+    """One lane's LANE_SUMS from its host net state, the close-out written
+    as the span after the last transmission less its two sleep spans."""
+    last, dl, dl2 = (net[k][:n] for k in ("last_end", "deadline",
+                                           "deadline2"))
+    t = max(t_end, last.max())
+    with np.errstate(invalid="ignore"):
+        s1 = dl + pol.state.t_s           # row-1 sleep starts
+        s2 = dl2 + pol.deep.t_s           # row-2 sleep starts
+        sleep1 = np.where(s1 < t, np.minimum(t, dl2) - s1, 0.0)
+        sleep2 = np.where(s2 < t, t - s2, 0.0)
+    wake = (t - last) - sleep1 - sleep2
+    return np.array(
+        [(net["time_wake"][:n] + wake).sum(),
+         (net["time_sleep"][:n] + sleep1).sum(),
+         (net["time_sleep2"][:n] + sleep2).sum(), last.max()]
+        + [net[k][:n].sum() for k in ("n_wake", "n_hit", "n_miss",
+                                      "n_deep")], np.float64)
+
+
+@pytest.mark.parametrize("kind", sorted({p.kind for p in GRID.values()}))
+def test_lane_sums_match_numpy(topo, pm, kind):
+    """Every lane of every static group of a kind reduces, on the device,
+    to what numpy computes from the same full net state."""
+    import jax
+
+    from repro.core import replay
+    from repro.traffic.plan import compile_plan
+    pols = {n: p for n, p in GRID.items() if p.kind == kind}
+    plan = compile_plan(_mini_trace(topo), topo)
+    for names in W.group_policies(pols):
+        group = [pols[n] for n in names]
+        nets, t_end, _, _, _ = replay.replay_plan(plan, group, pm)
+        got = S.lane_sums(nets, t_end, group, topo.n_links)
+        assert got.shape == (len(group), len(S.LANE_SUMS))
+        host = jax.tree.map(np.asarray, nets)
+        for b, pol in enumerate(group):
+            lane = {k: v[b] for k, v in host.items() if k != "pred"}
+            np.testing.assert_allclose(
+                got[b], _np_lane_sums(lane, t_end[b], pol, topo.n_links),
+                rtol=1e-12, err_msg=names[b])
+
+
+def test_sweep_cells_reads_back_only_lane_sums(topo, pm):
+    """A (traces x policies) grid brings to the host each lane's end time,
+    latency sums and LANE_SUMS row, and no array of its net state."""
+    from repro.core.instrument import span_log
+    traces = {"a": _mini_trace(topo, seed=3), "b": _mini_trace(topo, seed=5)}
+    pols = {n: GRID[n] for n in ("none", "fixed/fw/10us", "fixed/ds/100us",
+                                 "pb/ds/1pct")}
+    with span_log() as log:
+        out = W.sweep_scenarios(traces, topo, pols, pm)
+    lanes = len(traces) * len(pols)
+    assert sum(map(len, out.values())) == lanes
+    assert log.counts["readback_bytes"] == 8 * lanes * (3 + len(S.LANE_SUMS))
+    assert log.counts["readback_bytes"] < 8 * (topo.n_links + 1)
+
+
 def test_asleep_frac_in_unit_interval(swept):
     _, results = swept
     for name, res in results.items():
