@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.core import ieee
 from repro.core.eee import policy_params
 
 MAXH = 7  # hop-count histogram rows 0..6 (Megafly max 5, fat-tree 6)
@@ -52,9 +53,21 @@ def _log(x):
 
 
 def bin_index(gap, policy, params=None):
-    """gap (seconds) -> bin id in [0, B)."""
+    """gap (seconds) -> bin id in [0, B).
+
+    A gap given as its float64 bit pattern (``ieee.ExactClock``) takes on
+    linear bins the bin of IEEE float64 division, counted by comparison
+    against the bin edges the host computes (``ieee.quotient_edges``):
+    the TPU's emulated division can round a gap on an edge into the
+    next bin."""
     p = _params(policy, params)
     B = policy.hist_bins
+    if jnp.result_type(gap) == jnp.uint64:
+        if policy.hist_log_bins:
+            return bin_index(ieee.value(gap), policy, p)
+        edges = p[ieee.BITS + "hist_edges"]
+        return jnp.sum(ieee.key(gap)[..., None] >= ieee.key(edges),
+                       axis=-1, dtype=jnp.int32)
     if policy.hist_log_bins:
         lo, hi = _log(p["hist_log_min"]), _log(p["hist_log_max"])
         x = (jnp.log(jnp.maximum(gap, p["hist_log_min"])) - lo) / (hi - lo)
@@ -122,15 +135,21 @@ def init_state(n_links, policy, params=None):
     return st
 
 
-def _initial_tpdt(policy, params=None):
-    p = _params(policy, params)
+def initial_tpdt_param(policy):
+    """The parameter that arms every port's first t_PDT (``None``: the
+    ports never sleep)."""
     if policy.kind == "none":
-        return jnp.inf
+        return None
     if policy.kind in ("fixed", "dual", "coalesce", "precoalesce", "predict"):
         # predict starts dual-like: the forecaster takes over per port as
         # soon as the first gap lands in its histogram
-        return p["t_pdt"]
-    return p["tpdt_init"]
+        return "t_pdt"
+    return "tpdt_init"
+
+
+def _initial_tpdt(policy, params=None):
+    name = initial_tpdt_param(policy)
+    return jnp.inf if name is None else _params(policy, params)[name]
 
 
 # ---------------------------------------------------------------------------
@@ -141,11 +160,14 @@ def _initial_tpdt(policy, params=None):
 # ---------------------------------------------------------------------------
 
 
-def record_gaps(st, lp, gap, t_now, active, policy, params=None):
-    """Insert inactivity gaps.  lp,gap,t_now,active: (K,) or (m, H)."""
+def record_gaps(st, lp, gap, t_now, active, policy, params=None,
+                gap_bits=None):
+    """Insert inactivity gaps.  lp,gap,t_now,active: (K,) or (m, H);
+    ``gap_bits``, the gaps' float64 bit patterns where the replay keeps
+    exact clocks, bins them exactly (``bin_index``)."""
     p = _params(policy, params)
     do = active & (gap > 0)
-    b = bin_index(gap, policy, p)
+    b = bin_index(gap if gap_bits is None else gap_bits, policy, p)
     g = jnp.where(do, gap, 0.0)
     inc = do.astype(st["counts"].dtype)
 

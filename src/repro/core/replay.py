@@ -8,7 +8,8 @@ accumulators) entirely on device across the whole trace:
     scanned step** (per batch lane — each policy's latency feedback gives
     it a different replay order), replacing the per-step host sorts;
   * delivery maxima update ``ready`` via **scatter-max** (invalid slots
-    carry -inf, so padding never races);
+    carry -inf, so padding never races; exact clocks scatter-max their
+    order keys, DESIGN.md §2);
   * compute advances and barriers are **scan-step branches**: a dense
     per-step clock delta plus a masked participant-max select;
   * message-less steps skip the message machinery through a ``lax.cond``
@@ -36,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.core import ieee
 from repro.core import simulator as S
 from repro.core.eee import (PARAM_FIELDS, Policy, PowerModel,
                             canonical_proto, policy_params)
@@ -45,8 +47,11 @@ from repro.core.instrument import add, span
 def stack_params(pols) -> dict:
     """Stack each policy's numeric parameter vector into (B,) f64 arrays."""
     cols = [policy_params(p) for p in pols]
-    return {f: jnp.asarray([c[f] for c in cols], jnp.float64)
-            for f in PARAM_FIELDS}
+    out = {f: jnp.asarray([c[f] for c in cols], jnp.float64)
+           for f in PARAM_FIELDS}
+    if S.clock_of(pols[0]) is ieee.ExactClock:
+        out.update(S.clock_param_bits(pols[0], cols))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -157,6 +162,14 @@ def _row_chain(rows):
     return pred_s[inv], last_s[inv]
 
 
+def _deliver(ready, dst, delivery, valid, C):
+    """``ready`` raised to each destination's latest valid ``delivery``: a
+    scatter-max in the order of the clock ``C`` (``C.key``, an integer
+    scatter-max for exact clocks); padding slots rank below every time."""
+    return C.unkey(C.key(ready).at[dst].max(
+        jnp.where(valid, C.key(delivery), C.lowest)))
+
+
 def _conflicts(links, nhops, valid):
     """(cap, cap) bool conflict matrix of one step's messages, on device.
 
@@ -200,6 +213,8 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
         "event collection requires the serial message scan"
     spec = S.chain_spec(proto) if mode == "chain" else None
     chained = spec is not None
+    C = S.clock_of(proto)
+    delta_key = "delta" if C is ieee.FloatClock else "delta_bits"
 
     def _wave_chain(net, p, msgs, valid_s):
         """Message phase as a CHAINED wave loop for ONE policy lane.
@@ -370,7 +385,7 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
         # masked (provably no-op) extra waves
         net, delivery, lat, _ = lax.while_loop(
             lambda st: st[3] <= wmax, body,
-            (net, t_inj_s, jnp.zeros_like(t_inj_s), jnp.int32(1)))
+            (net, t_inj_s, jnp.zeros(t_inj_s.shape), jnp.int32(1)))
         return net, delivery, lat
 
     def _prefix_phase(net, p, msgs, valid_s, nv):
@@ -401,7 +416,7 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
         # delivery = t_inj / lat = 0 reproduces the scan's outputs bitwise
         net, delivery, lat, _ = lax.while_loop(
             lambda st: st[3] < nv, body,
-            (net, t_inj_s, jnp.zeros_like(t_inj_s), jnp.int32(0)))
+            (net, t_inj_s, jnp.zeros(t_inj_s.shape), jnp.int32(0)))
         return net, delivery, lat
 
     def _lane(net, p, ready, lat_sum, lat_max, mx, extra):
@@ -414,7 +429,7 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
         if needs_sort:
             # stable sort, padding keyed to +inf: the valid prefix orders
             # exactly like the reference engine's host np.argsort
-            order = jnp.argsort(jnp.where(valid, t_inj, jnp.inf),
+            order = jnp.argsort(jnp.where(valid, C.key(t_inj), C.top),
                                 stable=True)
             dst_s = dst[order]
             valid_s = valid[order]
@@ -446,7 +461,7 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
 
             net, out = lax.scan(msg_step, net, msgs)
             delivery, lat = out[0], out[1]
-        ready = ready.at[dst_s].max(jnp.where(valid_s, delivery, -jnp.inf))
+        ready = _deliver(ready, dst_s, delivery, valid_s, C)
         lat_sum = lat_sum + lat.sum()
         lat_max = jnp.maximum(lat_max, lat.max())
         if collect_events:
@@ -458,7 +473,7 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
 
         def step(carry, x):
             nets, ready, lat_sum, lat_max = carry
-            ready = ready + x["delta"][None]
+            ready = C.add(ready, x[delta_key][None])
             ev = None
             if cap:
                 mx = (x["src"], x["dst"], x["nbytes"], x["links"],
@@ -492,7 +507,8 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
                     nets, ready, lat_sum, lat_max, ev = out
                 else:
                     nets, ready, lat_sum, lat_max = out
-            rmax = jnp.max(jnp.where(part_mask, ready, -jnp.inf), axis=-1)
+            rmax = C.unkey(jnp.max(jnp.where(part_mask, C.key(ready),
+                                             C.lowest), axis=-1))
             ready = jnp.where(x["barrier"] & part_mask, rmax[:, None], ready)
             return (nets, ready, lat_sum, lat_max), ev
 
@@ -535,11 +551,31 @@ def _multi_segment_runner(proto: Policy, pm: PowerModel, n_links: int,
 # ---------------------------------------------------------------------------
 
 
+def segment_xs(seg, proto: Policy) -> dict:
+    """A plan segment's step arrays for a replay of ``proto``'s group:
+    on exact clocks with the clock deltas' bit patterns besides
+    (``PlanSegment.delta_bits``)."""
+    if S.clock_of(proto) is ieee.FloatClock:
+        return seg.xs
+    return dict(seg.xs, delta_bits=seg.delta_bits())
+
+
+def _clock_of_values(x):
+    return ieee.ExactClock if x.dtype == jnp.uint64 else ieee.FloatClock
+
+
 @jax.jit
 def _participant_max(mask, ready):
     """Per-lane makespan: max ``ready`` over participants.  Jitted so the
     -inf fill is a compile-time constant (keeps warm replays transfer-free)."""
-    return jnp.max(jnp.where(mask, ready, -jnp.inf), axis=-1)
+    C = _clock_of_values(ready)
+    return C.unkey(jnp.max(jnp.where(mask, C.key(ready), C.lowest), axis=-1))
+
+
+def host_times(x) -> np.ndarray:
+    """Device clock values as host float64, whichever clock kept them."""
+    x = np.asarray(x)
+    return x.view(np.float64) if x.dtype == np.uint64 else x
 
 
 def init_lanes(pols, plan):
@@ -551,7 +587,7 @@ def init_lanes(pols, plan):
     nets = jax.vmap(
         lambda p: S.init_net(plan.n_links, proto, params=p))(params)
     B = next(iter(params.values())).shape[0]
-    carry = (nets, jnp.zeros((B, plan.n_nodes), jnp.float64),
+    carry = (nets, jnp.zeros((B, plan.n_nodes), S.clock_of(proto).dtype),
              jnp.zeros((B,), jnp.float64), jnp.zeros((B,), jnp.float64))
     return proto, params, carry
 
@@ -571,7 +607,7 @@ def run_segments(plan, proto, params, pm, carry, collect_events=False):
         run = _segment_runner(proto, pm, plan.n_links, seg.cap,
                               collect_events, md, ns)
         carry, evs = run(carry[0], params, carry[1], carry[2], carry[3],
-                         plan.part_mask, seg.xs)
+                         plan.part_mask, segment_xs(seg, proto))
         if collect_events and seg.cap:
             seg_events.append((seg, evs))
     nets, ready, lat_sum, lat_max = carry
@@ -591,7 +627,7 @@ def replay_plan(plan, pols, pm, collect_events=False):
     proto, params, carry = init_lanes(pols, plan)
     nets, t_end, lat_sum, lat_max, seg_events = run_segments(
         plan, proto, params, pm, carry, collect_events)
-    return (nets, np.asarray(t_end), np.asarray(lat_sum),
+    return (nets, host_times(t_end), np.asarray(lat_sum),
             np.asarray(lat_max), seg_events)
 
 
@@ -604,7 +640,9 @@ def replay_plan(plan, pols, pm, collect_events=False):
 def _participant_max_multi(mask, ready):
     """Per-(trace, lane) makespan: max ``ready`` over each trace's own
     participants.  mask (T, n_nodes), ready (T, B, n_nodes) -> (T, B)."""
-    return jnp.max(jnp.where(mask[:, None, :], ready, -jnp.inf), axis=-1)
+    C = _clock_of_values(ready)
+    return C.unkey(jnp.max(jnp.where(mask[:, None, :], C.key(ready),
+                                     C.lowest), axis=-1))
 
 
 @lru_cache(maxsize=None)
@@ -619,7 +657,7 @@ def _multi_init(proto: Policy, n_links: int, n_nodes: int, T: int):
         B = next(iter(params.values())).shape[0]
         nets = jax.tree.map(
             lambda x: jnp.broadcast_to(x, (T,) + x.shape), nets1)
-        return (nets, jnp.zeros((T, B, n_nodes), jnp.float64),
+        return (nets, jnp.zeros((T, B, n_nodes), S.clock_of(proto).dtype),
                 jnp.zeros((T, B), jnp.float64), jnp.zeros((T, B), jnp.float64))
     return init
 
@@ -633,6 +671,8 @@ def init_lanes_multi(pols, batch):
     params = stack_params(pols)
     carry = _multi_init(proto, batch.n_links, batch.n_nodes,
                         batch.n_traces)(params)
+    # the lanes' net and predictor state, node clocks and accumulators
+    add("lane_state_bytes", sum(x.nbytes for x in jax.tree.leaves(carry)))
     return proto, params, carry
 
 
@@ -646,7 +686,7 @@ def run_segments_multi(batch, proto, params, pm, carry):
         run = _multi_segment_runner(proto, pm, batch.n_links, seg.cap,
                                     md, ns)
         carry, _ = run(carry[0], params, carry[1], carry[2], carry[3],
-                       batch.part_mask, seg.xs)
+                       batch.part_mask, segment_xs(seg, proto))
     nets, ready, lat_sum, lat_max = carry
     t_end = _participant_max_multi(batch.part_mask, ready)
     return nets, t_end, lat_sum, lat_max
@@ -666,7 +706,7 @@ def replay_plans(batch, pols, pm):
         nets, t_end, lat_sum, lat_max = run_segments_multi(
             batch, proto, params, pm, carry)
     with span("repro.replay.wait"):
-        t_end = np.asarray(t_end)
+        t_end = host_times(t_end)
     lat_sum, lat_max = np.asarray(lat_sum), np.asarray(lat_max)
     add("readback_bytes", t_end.nbytes + lat_sum.nbytes + lat_max.nbytes)
     # traces with no participants have an all-False mask row (-inf max)

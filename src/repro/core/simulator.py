@@ -36,11 +36,48 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.core import ieee
 from repro.core import perfbound as pb
 from repro.core.eee import Policy, PowerModel, policy_params
 from repro.traffic.plan import compile_plan, pad_message_table
 
 MAX_HOPS = 5
+
+
+# ---------------------------------------------------------------------------
+# Clocks
+# ---------------------------------------------------------------------------
+
+#: Whether kinds that record inactivity gaps keep their clocks as float64
+#: bit patterns (``ieee.ExactClock``); ``None`` follows the process's
+#: backend: exact on the TPU, plain float64 (the same bits) elsewhere.
+EXACT_CLOCKS = None
+
+
+def clock_of(policy: Policy):
+    """The clock arithmetic of a policy's replay (DESIGN.md §2).
+
+    A kind that records inactivity gaps (PerfBound and its variants,
+    ``predict``, ``record_hist``) decides on ``t_avail - last``, where a
+    queued message's gap is zero or an IEEE rounding residue: the TPU, whose
+    float64 is a pair of float32s, can reproduce neither, so there those
+    kinds carry every clock as the float64's bit pattern with IEEE
+    arithmetic (``ieee.ExactClock``).  Every other kind, and every kind on a
+    backend with a float64 unit, keeps plain float64."""
+    records = policy.adaptive or policy.record_hist
+    exact = EXACT_CLOCKS if EXACT_CLOCKS is not None \
+        else jax.default_backend() == "tpu"
+    if exact and records and policy.kind not in ("coalesce", "precoalesce"):
+        return ieee.ExactClock
+    return ieee.FloatClock
+
+
+def clock_param_bits(policy: Policy, cols: list) -> dict:
+    """The host bit patterns an exact-clock replay of ``policy``'s group
+    reads (``ieee.param_bits``), for the parameter dicts ``cols``: the
+    clock parameters, and the bin edges of a linear histogram."""
+    linear = not policy.hist_log_bins
+    return ieee.param_bits(cols, policy.hist_bins if linear else 0)
 
 
 # ---------------------------------------------------------------------------
@@ -56,13 +93,15 @@ def init_net(n_links, policy: Policy, params=None):
     # (clamped) t_dst past the sleep deadline; for single-state kinds
     # t_dst = +inf keeps the deep row of the FSM unreachable.
     p = pb._params(policy, params)
-    dl0 = pb._initial_tpdt(policy, params)
-    dl2_0 = dl0 + jnp.maximum(p["t_dst"], p["t_s"])
+    C = clock_of(policy)
+    first = pb.initial_tpdt_param(policy)
+    dl0 = C.const(float("inf")) if first is None else C.param(p, first)
+    dl2_0 = C.add(dl0, C.maximum(C.param(p, "t_dst"), C.param(p, "t_s")))
     net = {
-        "dir_free": jnp.zeros((2 * n_links + 1,), jnp.float64),
-        "last_end": jnp.zeros((P,), jnp.float64),
-        "deadline": jnp.full((P,), dl0, jnp.float64),
-        "deadline2": jnp.full((P,), dl2_0, jnp.float64),
+        "dir_free": jnp.zeros((2 * n_links + 1,), C.dtype),
+        "last_end": jnp.zeros((P,), C.dtype),
+        "deadline": jnp.full((P,), dl0, C.dtype),
+        "deadline2": jnp.full((P,), dl2_0, C.dtype),
         "time_wake": jnp.zeros((P,), jnp.float64),
         "time_sleep": jnp.zeros((P,), jnp.float64),
         "time_sleep2": jnp.zeros((P,), jnp.float64),
@@ -120,18 +159,24 @@ def _slot_compute(g, msg, active, policy: Policy, pm: PowerModel,
     links, dirs, nhops, t_inj, nbytes, valid = msg
     H = links.shape[-1]
     p = pb._params(policy, params)
-    t_w = p["t_w"] + p["sync_overhead"]
-    t_s = p["t_s"]
+    # clock arithmetic (``clock_of``): plain float64, or float64 bit
+    # patterns with IEEE roundings; the energy integration below reads
+    # the clocks' float64 values
+    C = clock_of(policy)
+    sync = C.param(p, "sync_overhead")
+    t_w = C.add(C.param(p, "t_w"), sync)
+    t_s = C.param(p, "t_s")
     # FSM row 2 (Deep Sleep): reachable only past ``deadline2``, which
     # single-state kinds pin to +inf (t_dst = inf) — every row-2 branch
     # below then selects the row-1 value, reproducing the single-state
     # arithmetic bit for bit.
-    t_w2 = p["t_w2"] + p["sync_overhead"]
-    t_s2 = p["t_s2"]
+    t_w2 = C.add(C.param(p, "t_w2"), sync)
+    t_s2 = C.param(p, "t_s2")
+    zero = C.const(0.0)
     coal = policy.kind == "coalesce"
     pre = policy.kind == "precoalesce"
     defer_on = coal or pre
-    t_ser = nbytes / pm.link_bandwidth
+    t_ser = C.tser(nbytes, pm.link_bandwidth)
 
     free = g["free"]
     last = g["last"]
@@ -164,20 +209,22 @@ def _slot_compute(g, msg, active, policy: Policy, pm: PowerModel,
     def _fsm(ta, dl_h, dl2_h, defer_h):
         """One port's FSM read at raw arrival ``ta``: (asleep, deep,
         in_down, in_down2, effective arrival, wake penalty)."""
-        asleep = ta >= dl_h
+        asleep = C.ge(ta, dl_h)
         tae = ta + jnp.where(asleep, defer_h, 0.0) if defer_on else ta
-        deep = tae >= dl2_h
-        in_down = asleep & (tae < dl_h + t_s)
-        in_down2 = deep & (tae < dl2_h + t_s2)
-        pen_fast = jnp.where(in_down, dl_h + t_s - tae, 0.0) + t_w
-        pen_deep = jnp.where(in_down2, dl2_h + t_s2 - tae, 0.0) + t_w2
-        pen = jnp.where(asleep, jnp.where(deep, pen_deep, pen_fast), 0.0)
+        deep = C.ge(tae, dl2_h)
+        down_end, down2_end = C.add(dl_h, t_s), C.add(dl2_h, t_s2)
+        in_down = asleep & C.lt(tae, down_end)
+        in_down2 = deep & C.lt(tae, down2_end)
+        pen_fast = C.add(jnp.where(in_down, C.sub(down_end, tae), zero), t_w)
+        pen_deep = C.add(jnp.where(in_down2, C.sub(down2_end, tae), zero),
+                         t_w2)
+        pen = jnp.where(asleep, jnp.where(deep, pen_deep, pen_fast), zero)
         return asleep, deep, in_down, in_down2, tae, pen
 
     # ---- unrolled 5-hop time chain (register-only) -----------------------
     t_head = t_inj
-    t_avail = jnp.zeros(active.shape, jnp.float64)
-    t_start = jnp.zeros(active.shape, jnp.float64)
+    t_avail = jnp.zeros(active.shape, C.dtype)
+    t_start = jnp.zeros(active.shape, C.dtype)
     if defer_on:
         # pre-occupancy arrival per hop: the moment the frame reaches the
         # port's queue, BEFORE waiting for the link to free — the time the
@@ -185,24 +232,25 @@ def _slot_compute(g, msg, active, policy: Policy, pm: PowerModel,
         # waking head is serviced after the release, but it joined before)
         t_arr = jnp.zeros(active.shape, jnp.float64)
     delivery = t_inj
+    hop = C.const(pm.switch_latency)
     for h in range(H):
-        ta = jnp.maximum(t_head, free[..., h])
+        ta = C.maximum(t_head, free[..., h])
         _, _, _, _, tae, pen = _fsm(ta, dl[..., h], dl2[..., h],
                                     defer_amt[..., h] if defer_on else 0.0)
-        ts_ = tae + pen
-        te_ = ts_ + t_ser
+        ts_ = C.add(tae, pen)
+        te_ = C.add(ts_, t_ser)
         t_avail = t_avail.at[..., h].set(ta)
         t_start = t_start.at[..., h].set(ts_)
         if defer_on:
             t_arr = t_arr.at[..., h].set(t_head)
-        t_head = jnp.where(active[..., h], ts_ + pm.switch_latency, t_head)
+        t_head = jnp.where(active[..., h], C.add(ts_, hop), t_head)
         delivery = jnp.where(active[..., h], te_, delivery)
 
-    t_end = t_start + t_ser[..., None]
+    t_end = C.add(t_start, t_ser[..., None])
     asleep, deep, in_down, in_down2, tae, _ = _fsm(
         t_avail, dl, dl2, defer_amt if defer_on else 0.0)
-    gap = t_avail - last
-    new_last = jnp.maximum(last, t_end)
+    gap = C.sub(t_avail, last)
+    new_last = C.maximum(last, t_end)
 
     # ---- energy time integration (frontier scheme) ------------------------
     # ``last_end`` is the accounting frontier: everything before it is
@@ -214,25 +262,33 @@ def _slot_compute(g, msg, active, policy: Policy, pm: PowerModel,
     # sleeps at the row-1 floor and — past the demotion deadline and its
     # second down transition — at the row-2 floor (zero spans if the packet
     # lands during a down transition).
-    wake_fast = (dl - last) + t_s + t_w + t_ser[..., None]
-    wake_deep = (dl - last) + t_s + t_s2 + t_w2 + t_ser[..., None]
+    v = C.val
+    dlv, dl2v, lastv, taev = v(dl), v(dl2), v(last), v(tae)
+    t_sv, t_s2v, t_wv, t_w2v = v(t_s), v(t_s2), v(t_w), v(t_w2)
+    t_serv = v(t_ser)
+    wake_fast = (dlv - lastv) + t_sv + t_wv + t_serv[..., None]
+    wake_deep = (dlv - lastv) + t_sv + t_s2v + t_w2v + t_serv[..., None]
     wake_add = jnp.where(asleep,
                          jnp.where(deep, wake_deep, wake_fast),
-                         jnp.maximum(new_last - last, 0.0))
+                         jnp.maximum(v(new_last) - lastv, 0.0))
     sleep_add = jnp.where(asleep & ~in_down,
-                          jnp.where(deep, dl2 - (dl + t_s),
-                                    jnp.maximum(tae - (dl + t_s), 0.0)),
+                          jnp.where(deep, dl2v - (dlv + t_sv),
+                                    jnp.maximum(taev - (dlv + t_sv), 0.0)),
                           0.0)
     sleep2_add = jnp.where(deep & ~in_down2,
-                           jnp.maximum(tae - (dl2 + t_s2), 0.0), 0.0)
+                           jnp.maximum(taev - (dl2v + t_s2v), 0.0), 0.0)
     a = active.astype(jnp.float64)
 
+    # clocks in the clock's representation (t_avail, t_start, t_end,
+    # new_last, delivery); gap, t_now and lat as float64 for the
+    # predictors and the latency sums
     out = dict(
-        active=active, a=a, asleep=asleep, deep=deep, gap=gap,
+        active=active, a=a, asleep=asleep, deep=deep, gap=v(gap),
+        gap_bits=None if C is ieee.FloatClock else gap,
         t_avail=t_avail, t_start=t_start, t_end=t_end, new_last=new_last,
-        wake_add=wake_add, sleep_add=sleep_add, sleep2_add=sleep2_add,
-        delivery=delivery,
-        lat=jnp.where(valid & (nhops > 0), delivery - t_inj, 0.0),
+        t_now=v(t_avail), wake_add=wake_add, sleep_add=sleep_add,
+        sleep2_add=sleep2_add, delivery=delivery,
+        lat=jnp.where(valid & (nhops > 0), v(delivery) - v(t_inj), 0.0),
     )
     if defer_on:
         # precoalesce: the cycle state advances only at the injection hop
@@ -251,6 +307,17 @@ def _slot_compute(g, msg, active, policy: Policy, pm: PowerModel,
     return out
 
 
+def _shift_rows(x, idx, delta, C):
+    """Rows ``idx`` of a clock array moved by ``delta``, in the clock's
+    arithmetic.  Bit patterns cannot scatter-add: they are gathered,
+    added and set, which is the same where ``idx`` repeats only the
+    dummy row (one message's hops; a wave's link-disjoint messages) and
+    every masked slot adds zero there."""
+    if C is ieee.FloatClock:
+        return x.at[idx].add(delta)
+    return x.at[idx].set(C.add(x[idx], delta))
+
+
 def _message_step(net, msg, policy: Policy, pm: PowerModel, n_links: int,
                   params=None):
     """Advance the net state by one message — or, when the message arrays
@@ -262,7 +329,7 @@ def _message_step(net, msg, policy: Policy, pm: PowerModel, n_links: int,
     members serially in any order (DESIGN.md §10)."""
     links, dirs, nhops, t_inj, nbytes, valid = msg
     p = pb._params(policy, params)
-    t_s = p["t_s"]
+    C = clock_of(policy)
     coal = policy.kind == "coalesce"
     pre = policy.kind == "precoalesce"
     defer_on = coal or pre
@@ -283,7 +350,7 @@ def _message_step(net, msg, policy: Policy, pm: PowerModel, n_links: int,
     ns = _slot_compute(g, msg, active, policy, pm, params)
     a = ns["a"]
     asleep, deep, gap = ns["asleep"], ns["deep"], ns["gap"]
-    t_avail, t_start, t_end = ns["t_avail"], ns["t_start"], ns["t_end"]
+    t_start, t_end = ns["t_start"], ns["t_end"]
     new_last, dl, dl2 = ns["new_last"], g["dl"], g["dl2"]
 
     net = dict(
@@ -305,15 +372,23 @@ def _message_step(net, msg, policy: Policy, pm: PowerModel, n_links: int,
         net[ck[2]] = net[ck[2]].at[lp].set(new_release)
 
     # ---- occupancy / transmission-end bookkeeping -------------------------
-    net["dir_free"] = net["dir_free"].at[dp].add(
-        jnp.maximum(t_end - g["free"], 0.0) * a)
-    net["last_end"] = net["last_end"].at[lp].add((new_last - g["last"]) * a)
+    if C is ieee.FloatClock:
+        free_step = jnp.maximum(t_end - g["free"], 0.0) * a
+        last_step = (new_last - g["last"]) * a
+    else:
+        zero = C.const(0.0)
+        free_step = jnp.where(active, C.sub(C.maximum(g["free"], t_end),
+                                            g["free"]), zero)
+        last_step = jnp.where(active, C.sub(new_last, g["last"]), zero)
+    net["dir_free"] = _shift_rows(net["dir_free"], dp, free_step, C)
+    net["last_end"] = _shift_rows(net["last_end"], lp, last_step, C)
 
     # ---- predictors --------------------------------------------------------
     H = links.shape[-1]
     pred = net["pred"]
     if policy.adaptive or policy.record_hist:
-        pred = pb.record_gaps(pred, lp, gap, t_avail, active, policy, p)
+        pred = pb.record_gaps(pred, lp, gap, ns["t_now"], active, policy, p,
+                              gap_bits=ns.get("gap_bits"))
         pred = pb.record_hops(pred, lp, nhops[..., None] - jnp.arange(H),
                               active, policy)
     if policy.kind == "perfbound_correct":
@@ -322,7 +397,7 @@ def _message_step(net, msg, policy: Policy, pm: PowerModel, n_links: int,
     if policy.adaptive:
         if policy.kind == "perfbound_dual":
             new_tpdt, new_tdst = pb.compute_tpdt_tdst(
-                pred, lp, t_end, p["t_w"], policy, p)
+                pred, lp, C.val(t_end), p["t_w"], policy, p)
             pred = dict(pred, t_dst=pred["t_dst"].at[lp].set(
                 jnp.where(active, new_tdst, pred["t_dst"][lp])))
         elif policy.kind == "predict":
@@ -335,25 +410,28 @@ def _message_step(net, msg, policy: Policy, pm: PowerModel, n_links: int,
                 ewma=pred["ewma"].at[lp].set(
                     jnp.where(active, new_ewma, pred["ewma"][lp])))
         else:
-            new_tpdt = pb.compute_tpdt(pred, lp, t_end, p["t_w"], policy, p)
+            new_tpdt = pb.compute_tpdt(pred, lp, C.val(t_end), p["t_w"],
+                                       policy, p)
         pred = dict(pred, tpdt=pred["tpdt"].at[lp].set(
             jnp.where(active, new_tpdt, pred["tpdt"][lp])))
     net["pred"] = pred
 
     # deadline = end of PDT countdown after the latest transmission;
     # deadline2 = the demotion point a (clamped) t_dst further out
-    tpdt_now = net["pred"]["tpdt"][lp]
-    new_dl = jnp.where(active, new_last + tpdt_now, dl)
-    net["deadline"] = net["deadline"].at[lp].add(new_dl - dl)
-    tdst_now = net["pred"]["t_dst"][lp] \
-        if policy.kind in ("perfbound_dual", "predict") else p["t_dst"]
-    new_dl2 = jnp.where(active, new_dl + jnp.maximum(tdst_now, t_s), dl2)
+    tpdt_now = C.of(net["pred"]["tpdt"][lp])
+    new_dl = jnp.where(active, C.add(new_last, tpdt_now), dl)
+    net["deadline"] = _shift_rows(net["deadline"], lp, C.sub(new_dl, dl), C)
+    tdst_now = C.of(net["pred"]["t_dst"][lp]) \
+        if policy.kind in ("perfbound_dual", "predict") \
+        else C.param(p, "t_dst")
+    new_dl2 = jnp.where(
+        active, C.add(new_dl, C.maximum(tdst_now, C.param(p, "t_s"))), dl2)
     # masked SET, not scatter-add: adaptive t_dst legitimately swings
     # between +inf ("never demote") and finite, and inf - inf through an
     # add would latch the row at NaN, silently disabling demotion forever
     net["deadline2"] = net["deadline2"].at[lp].set(new_dl2)
 
-    events = (lp, t_start, t_end, active)
+    events = (lp, C.val(t_start), C.val(t_end), active)
     return net, (ns["delivery"], ns["lat"], events)
 
 
@@ -382,12 +460,17 @@ def chain_spec(policy: Policy):
 @lru_cache(maxsize=None)
 def _compiled_chunk(policy: Policy, pm: PowerModel, n_links: int,
                     collect_events: bool):
+    C = clock_of(policy)
+
     @partial(jax.jit, donate_argnums=(0,))
     def run(net, msgs, params):
+        if msgs[3].dtype != C.dtype:          # float64 injection times
+            msgs = msgs[:3] + (C.of(msgs[3]),) + msgs[4:]
+
         def step(net, m):
             net, (d, lat, ev) = _message_step(net, m, policy, pm, n_links,
                                               params=params)
-            out = (d, lat, ev) if collect_events else (d, lat)
+            out = (C.val(d), lat, ev) if collect_events else (C.val(d), lat)
             return net, out
         return lax.scan(step, net, msgs)
     return run
@@ -400,7 +483,11 @@ def sim_chunk(net, msgs, policy, pm, n_links, collect_events=False):
     replay: baked in as constants, XLA would rewrite ``gap / bin_width``
     into a multiply by the rounded reciprocal and bin a gap on a bin edge
     one bin lower than the replay does."""
-    params = {k: jnp.float64(v) for k, v in policy_params(policy).items()}
+    pp = policy_params(policy)
+    params = {k: jnp.float64(v) for k, v in pp.items()}
+    if clock_of(policy) is ieee.ExactClock:
+        params.update({k: v[0] for k, v in
+                       clock_param_bits(policy, [pp]).items()})
     return _compiled_chunk(policy, pm, n_links, collect_events)(
         net, msgs, params)
 
@@ -410,14 +497,20 @@ def sim_chunk(net, msgs, policy, pm, n_links, collect_events=False):
 # ---------------------------------------------------------------------------
 
 
+def _as_float(x):
+    """Clock values as float64, whichever clock kept them."""
+    x = jnp.asarray(x)
+    return ieee.value(x) if x.dtype == jnp.uint64 else x
+
+
 def _close_out(net, t_end_sim, t_s, t_s2, n_links: int):
     """``close_out`` on the row-1 and row-2 down-transition times ``t_s``
     and ``t_s2``: plain numbers or traced operands alike."""
     # jnp inputs throughout: raw numpy would warn on the (masked-away)
     # inf-inf deep spans of never-woken links
-    last = jnp.asarray(net["last_end"][:n_links])
-    dl = jnp.asarray(net["deadline"][:n_links])
-    dl2 = jnp.asarray(net["deadline2"][:n_links])
+    last = _as_float(net["last_end"][:n_links])
+    dl = _as_float(net["deadline"][:n_links])
+    dl2 = _as_float(net["deadline2"][:n_links])
     t_end_sim = jnp.maximum(t_end_sim, last.max())
     sleeps = dl + t_s < t_end_sim
     deeps = dl2 + t_s2 < t_end_sim
@@ -458,7 +551,8 @@ _REDUCED = ("last_end", "deadline", "deadline2", "time_wake", "time_sleep",
 def _lane_sums(net, t_end, t_s, t_s2, n_links):
     tw, ts_, ts2 = _close_out(net, t_end, t_s, t_s2, n_links)
     return jnp.stack(
-        [tw.sum(), ts_.sum(), ts2.sum(), net["last_end"][:n_links].max()]
+        [tw.sum(), ts_.sum(), ts2.sum(),
+         _as_float(net["last_end"][:n_links]).max()]
         + [net[k][:n_links].sum().astype(jnp.float64) for k in _COUNTERS])
 
 

@@ -50,7 +50,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import replay
+from repro.core import ieee, replay
+from repro.core import simulator as S
 from repro.core.eee import Policy, PowerModel, canonical_proto
 from repro.core.instrument import add, span
 from repro.traffic.plan import PlanBatch
@@ -237,7 +238,12 @@ def replay_plans_sharded(batch: PlanBatch, pols, pm: PowerModel,
                  put(carry[3], SP_TB))
         part_mask, seg_xs = _place_batch(batch, mesh, T_pad)
 
+        exact = S.clock_of(proto) is not ieee.FloatClock
         for seg, xs in zip(batch.segments, seg_xs):
+            if exact:
+                xs = dict(xs, delta_bits=jax.device_put(
+                    _pad_T(seg.delta_bits(), T_pad),
+                    NamedSharding(mesh, SP_T)))
             md, ns = replay._seg_flags(seg, proto)
             run = _sharded_runner(proto, pm, batch.n_links, seg.cap, mesh,
                                   md, ns)
@@ -247,7 +253,7 @@ def replay_plans_sharded(batch: PlanBatch, pols, pm: PowerModel,
         t_end = replay._participant_max_multi(part_mask, ready)
 
     with span("repro.replay.wait"):
-        t_end = np.asarray(t_end)
+        t_end = replay.host_times(t_end)
     lat_sum, lat_max = np.asarray(lat_sum), np.asarray(lat_max)
     add("readback_bytes", t_end.nbytes + lat_sum.nbytes + lat_max.nbytes)
     t_end = np.where(batch.has_participants[:, None], t_end[:T, :B], 0.0)

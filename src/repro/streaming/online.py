@@ -51,8 +51,9 @@ HOST_LAYERS = {
     "summarize": ("repro.sweep.summarize",),
     "decide": ("repro.stream.decide",),
 }
-#: a re-advice's counters: bytes placed on the device, bytes read back
-COUNTERS = ("readback_bytes", "placed_bytes")
+#: a re-advice's counters: bytes placed on the device, bytes read back,
+#: bytes of the lanes' initial replay state made on the device
+COUNTERS = ("readback_bytes", "placed_bytes", "lane_state_bytes")
 
 
 def challenger_pool(topo, *, family: str = "dc", n_nodes: int = 16,
@@ -122,7 +123,9 @@ def advise_stream(spec: DriftSpec, topo, *,
       ``summarize`` the per-lane statistics, ``decide`` the controller
       and accounting), each the summed ``instrument.span`` durations of
       that layer; and ``counts`` — ``placed_bytes`` (plan bytes put on
-      the device) and ``readback_bytes`` (bytes brought back to host);
+      the device), ``readback_bytes`` (bytes brought back to host) and
+      ``lane_state_bytes`` (the lanes' initial replay state, net and
+      predictor, made on the device);
     * ``totals`` — stream-level accounting: online energy/overhead vs the
       always-on baseline AND vs the best single static policy in
       hindsight (the lowest-total-energy pool candidate whose TOTAL

@@ -135,6 +135,8 @@ class PlanSegment:
     host_has_msgs: np.ndarray = field(default=None, repr=False)  # (S_pad,)
     host_live: np.ndarray = field(default=None, repr=False)      # (S_pad,) i32
     host_wave: np.ndarray = field(default=None, repr=False)      # (S_pad,) i32
+    host_delta: np.ndarray = field(default=None, repr=False)  # xs["delta"]
+    _delta_bits: Optional[jnp.ndarray] = field(default=None, repr=False)
 
     @property
     def s_pad(self) -> int:
@@ -179,6 +181,16 @@ class PlanSegment:
             return float(self.cap)
         wv = self.host_wave[self.host_wave > 0]
         return float(wv.mean()) if wv.size else 0.0
+
+    def delta_bits(self) -> jnp.ndarray:
+        """The clock deltas as float64 bit patterns on the device, placed
+        on first use: only a replay on exact clocks (``ieee.ExactClock``)
+        reads them, and the TPU cannot take them from the placed
+        float64, whose bits it does not hold."""
+        if self._delta_bits is None:
+            self._delta_bits = jnp.asarray(self.host_delta.view(np.uint64))
+            add("placed_bytes", self.host_delta.nbytes)
+        return self._delta_bits
 
     def nbytes(self) -> int:
         """Device bytes held by this segment's arrays."""
@@ -385,7 +397,7 @@ def _stack_segment(steps: List[_HostStep], cap: int, n_nodes: int,
             links=jnp.asarray(links), dirs=jnp.asarray(dirs),
             nhops=jnp.asarray(nhops), valid=jnp.asarray(valid))
     return PlanSegment(cap=cap, n_steps=S, xs=xs, host_has_msgs=has_msgs,
-                       host_live=live, host_wave=wave)
+                       host_live=live, host_wave=wave, host_delta=delta)
 
 
 def topo_signature(topo) -> tuple:
@@ -632,7 +644,9 @@ def stack_plans(plans: List[TracePlan], names: Optional[List[str]] = None
             cap=seg0.cap,
             n_steps=max(p.segments[si].n_steps for p in plans),
             xs=xs, host_has_msgs=host_has, host_live=host_live,
-            host_wave=host_wave))
+            host_wave=host_wave,
+            host_delta=np.stack([p.segments[si].host_delta
+                                 for p in plans])))
     return PlanBatch(
         n_nodes=plans[0].n_nodes, n_links=plans[0].n_links,
         max_hops=plans[0].max_hops,
@@ -724,11 +738,14 @@ def _apply_schedule(plan: TracePlan, schedule: List[tuple]) -> TracePlan:
             [s.host_wave[:keep] if s.host_wave is not None
              else np.zeros((keep,), np.int32)
              for s, (_, keep) in zip(segs, members)]), S_pad, 0)
+        host_delta = _pad_axis(np.concatenate(
+            [s.host_delta[:keep]
+             for s, (_, keep) in zip(segs, members)]), S_pad, 0)
         segments.append(PlanSegment(
             cap=cap, n_steps=S,
             xs={k: jnp.asarray(v) for k, v in xs.items()},
             host_has_msgs=host_has, host_live=host_live,
-            host_wave=host_wave))
+            host_wave=host_wave, host_delta=host_delta))
     return replace(plan, segments=segments)
 
 

@@ -114,3 +114,37 @@ def test_replay_segment_compiles(one_chip, policy):
     compiled = run.lower(*args).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16e9
+
+
+def test_exact_clock_segment_compiles(one_chip, monkeypatch):
+    """A PerfBound lane keeps exact clocks on the TPU (its float64 bit
+    patterns, integer IEEE arithmetic): the stream's prefix executor for
+    one dc-poisson segment compiles for v5e with the integer path."""
+    from repro.core import ieee
+    from repro.core import simulator as S
+    monkeypatch.setattr(S, "EXACT_CLOCKS", True)
+    caches = (replay._multi_segment_runner, replay._multi_init)
+    for f in caches:
+        f.cache_clear()
+    policy = Policy(kind="perfbound", bound=0.01, hist_mode="keep_all")
+    assert S.clock_of(policy) is ieee.ExactClock
+    topo = small_topology()
+    spec = resolve(["dc-poisson"])["dc-poisson"]
+    batch = stack_plans([compile_plan(build_trace(spec, topo), topo)])
+    proto, params, carry = replay.init_lanes_multi([policy], batch)
+    seg = batch.segments[0]
+    with replay.wavefront_mode("prefix"):
+        mode, needs_sort = replay._seg_flags(seg, proto)
+    run = replay._multi_segment_runner(proto, PowerModel(), batch.n_links,
+                                       seg.cap, mode, needs_sort)
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (carry[0], params, carry[1], carry[2], carry[3], batch.part_mask,
+         replay.segment_xs(seg, proto)))
+    try:
+        compiled = run.lower(*args).compile()
+    finally:
+        for f in caches:
+            f.cache_clear()
+    assert "u32" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
