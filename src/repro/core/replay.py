@@ -29,6 +29,7 @@ carry never leaves the device (``tests/test_plan.py`` pins this with a
 """
 from __future__ import annotations
 
+from collections import OrderedDict
 from contextlib import contextmanager
 from functools import lru_cache, partial
 
@@ -44,14 +45,38 @@ from repro.core.eee import (PARAM_FIELDS, Policy, PowerModel,
 from repro.core.instrument import add, span
 
 
+# (policies, clock, x64, default device) -> stacked params; LRU
+_PARAMS_CACHE: "OrderedDict[tuple, dict]" = OrderedDict()
+_PARAMS_CACHE_MAX = 64
+
+
 def stack_params(pols) -> dict:
-    """Stack each policy's numeric parameter vector into (B,) f64 arrays."""
+    """Stack each policy's numeric parameter vector into (B,) f64 arrays
+    (and, on exact clocks, their bit patterns: ``S.clock_param_bits``).
+
+    The arrays are placed on the device once per group: a bounded LRU
+    keyed on the policies (frozen, compared by value) and on what decides
+    the arrays' dtype, clock and device, so a warm replay of the same group
+    places nothing.  Every call returns a fresh dict over the shared
+    arrays; no program may donate them (no runner donates ``params``).
+    Each placement adds 1 to the counter ``params_placed``."""
+    clock = S.clock_of(pols[0])
+    key = (tuple(pols), clock, jax.config.jax_enable_x64,
+           jax.config.jax_default_device)
+    out = _PARAMS_CACHE.get(key)
+    if out is not None:
+        _PARAMS_CACHE.move_to_end(key)
+        return dict(out)
+    add("params_placed", 1)
     cols = [policy_params(p) for p in pols]
     out = {f: jnp.asarray([c[f] for c in cols], jnp.float64)
            for f in PARAM_FIELDS}
-    if S.clock_of(pols[0]) is ieee.ExactClock:
+    if clock is ieee.ExactClock:
         out.update(S.clock_param_bits(pols[0], cols))
-    return out
+    _PARAMS_CACHE[key] = out
+    while len(_PARAMS_CACHE) > _PARAMS_CACHE_MAX:
+        _PARAMS_CACHE.popitem(last=False)
+    return dict(out)
 
 
 # ---------------------------------------------------------------------------
@@ -579,9 +604,10 @@ def host_times(x) -> np.ndarray:
 
 
 def init_lanes(pols, plan):
-    """Lane setup (the only host->device traffic of a replay): canonical
-    proto, stacked params, and the initial scan carry — batched net state,
-    zeroed per-node ``ready`` clocks, zeroed latency accumulators."""
+    """Lane setup: canonical proto, stacked params (placed on a group's
+    first replay, ``stack_params``), and the initial scan carry — batched
+    net state, zeroed per-node ``ready`` clocks, zeroed latency
+    accumulators."""
     proto = canonical_proto(pols[0])
     params = stack_params(pols)
     nets = jax.vmap(

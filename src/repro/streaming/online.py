@@ -52,8 +52,10 @@ HOST_LAYERS = {
     "decide": ("repro.stream.decide",),
 }
 #: a re-advice's counters: bytes placed on the device, bytes read back,
-#: bytes of the lanes' initial replay state made on the device
-COUNTERS = ("readback_bytes", "placed_bytes", "lane_state_bytes")
+#: bytes of the lanes' initial replay state made on the device, policy
+#: groups whose parameters were placed on the device
+COUNTERS = ("readback_bytes", "placed_bytes", "lane_state_bytes",
+            "params_placed")
 
 
 def challenger_pool(topo, *, family: str = "dc", n_nodes: int = 16,
@@ -125,7 +127,9 @@ def advise_stream(spec: DriftSpec, topo, *,
       that layer; and ``counts`` — ``placed_bytes`` (plan bytes put on
       the device), ``readback_bytes`` (bytes brought back to host) and
       ``lane_state_bytes`` (the lanes' initial replay state, net and
-      predictor, made on the device);
+      predictor, made on the device) and ``params_placed`` (policy groups
+      whose stacked parameters were placed on the device: 0 once the
+      pool is warm);
     * ``totals`` — stream-level accounting: online energy/overhead vs the
       always-on baseline AND vs the best single static policy in
       hindsight (the lowest-total-energy pool candidate whose TOTAL

@@ -10,10 +10,10 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import replay
+from repro.core import ieee, replay
 from repro.core import simulator as S
-from repro.core.eee import Policy, PowerModel
-from repro.core.instrument import count_compiles
+from repro.core.eee import Policy, PowerModel, static_key
+from repro.core.instrument import count_compiles, span_log
 from repro.core.sweep import sweep_policies
 from repro.topology.fattree import small_fattree
 from repro.topology.megafly import small_topology
@@ -302,6 +302,161 @@ def test_warm_replay_is_device_resident():
     assert cc.count == 0, "warm replay recompiled"
     t_end = float(out[1][0])                       # readback OUTSIDE guard
     assert t_end == warm_t_end > 0.0
+
+
+# ---------------------------------------------------------------------------
+# Parameter stacks: placed on a group's first replay, shared after
+# ---------------------------------------------------------------------------
+
+#: a float-clock group of two lanes, and a PerfBound group on exact clocks
+GROUPS = {
+    "fixed": (Policy(kind="fixed", t_pdt=1e-5, sleep_state="deep_sleep"),
+              Policy(kind="fixed", t_pdt=1e-4, sleep_state="deep_sleep")),
+    "perfbound": (Policy(kind="perfbound", bound=0.01, hist_mode="keep_all",
+                         sleep_state="deep_sleep"),),
+}
+
+
+def _clear_runners():
+    for f in (replay._multi_segment_runner, replay._multi_init):
+        f.cache_clear()
+
+
+def _bits(x):
+    return np.ascontiguousarray(np.asarray(x)).tobytes()
+
+
+@pytest.fixture(scope="module")
+def group_replays():
+    """Per group, three replays of one batch: ``cold`` (the parameter
+    cache emptied first), ``warm`` (under a host->device transfer guard),
+    ``uncached`` (the cache emptied again); each with the outputs' bytes,
+    its ``lane_sums`` and its ``params_placed`` count."""
+    topo = TOPOS["fattree"]
+    nodes = np.arange(8, dtype=np.int64)
+    tr = Trace(nodes=nodes, name="ring")
+    for r in range(3):
+        tr.compute(1e-5 * (r + 1))
+        tr.messages([[int(i), int((i + 1 + r) % 8), 2048 << r]
+                     for i in range(8)], barrier=(r == 2))
+    batch = P.stack_plans([P.compile_plan(tr, topo)], ["ring"])
+    out = {}
+    for name, pols in GROUPS.items():
+        runs = {}
+        with pytest.MonkeyPatch.context() as mp:
+            if name == "perfbound":
+                mp.setattr(S, "EXACT_CLOCKS", True)
+            _clear_runners()
+            try:
+                for label in ("cold", "warm", "uncached"):
+                    if label != "warm":
+                        replay._PARAMS_CACHE.clear()
+                    guard = jax.transfer_guard_host_to_device(
+                        "disallow" if label == "warm" else "allow")
+                    with span_log() as log, guard:
+                        nets, t_end, lat_sum, lat_max = replay.replay_plans(
+                            batch, list(pols), PM)
+                    lanes = S.lane_sums(nets, t_end, list(pols), topo.n_links)
+                    runs[label] = {
+                        "out": [_bits(x) for x in (t_end, lat_sum, lat_max,
+                                                  lanes)],
+                        "placed": log.counts.get("params_placed", 0),
+                        "t_end": np.asarray(t_end)}
+                runs["clock"] = S.clock_of(pols[0])
+            finally:
+                _clear_runners()
+        out[name] = runs
+    return out
+
+
+@pytest.mark.parametrize("group", sorted(GROUPS))
+def test_warm_group_replay_places_nothing(group_replays, group):
+    """A group's second replay takes its parameters from the cache: no
+    host->device transfer, no placement."""
+    runs = group_replays[group]
+    want = ieee.ExactClock if group == "perfbound" else ieee.FloatClock
+    assert runs["clock"] is want
+    assert [runs[k]["placed"] for k in ("cold", "warm", "uncached")] \
+        == [1, 0, 1]
+    assert (runs["warm"]["t_end"] > 0).all()
+
+
+@pytest.mark.parametrize("group", sorted(GROUPS))
+def test_cached_params_replay_is_bit_identical(group_replays, group):
+    """``t_end``, ``lat_sum``, ``lat_max`` and ``lane_sums`` of a replay
+    on cached parameters equal, bit for bit, those of replays on freshly
+    placed ones."""
+    runs = group_replays[group]
+    assert runs["warm"]["out"] == runs["uncached"]["out"] \
+        == runs["cold"]["out"]
+
+
+def test_params_cache_keys_on_the_numbers():
+    """Two groups of one static key but different numbers are two entries;
+    a hit returns a fresh dict over the same arrays."""
+    a = [Policy(kind="fixed", t_pdt=1e-5, sleep_state="deep_sleep")]
+    b = [Policy(kind="fixed", t_pdt=2e-5, sleep_state="deep_sleep")]
+    assert static_key(a[0]) == static_key(b[0])
+    replay._PARAMS_CACHE.clear()
+    with span_log() as log:
+        pa, pb, again = (replay.stack_params(p) for p in (a, b, a))
+    assert log.counts["params_placed"] == 2
+    assert len(replay._PARAMS_CACHE) == 2
+    assert float(pa["t_pdt"][0]) == 1e-5 and float(pb["t_pdt"][0]) == 2e-5
+    assert again is not pa and again.keys() == pa.keys()
+    assert all(again[k] is pa[k] for k in pa)
+    again["t_pdt"] = None                # a caller's dict is its own
+    assert replay.stack_params(a)["t_pdt"] is pa["t_pdt"]
+
+
+@pytest.mark.parametrize("setting", ["clock", "x64", "device"])
+def test_params_cache_keys_on_what_places_the_arrays(setting):
+    """The same group under another clock, precision or default device is
+    another entry, with that clock's keys, that dtype, that device."""
+    import warnings
+    pols = [Policy(kind="perfbound", bound=0.01)]
+    replay._PARAMS_CACHE.clear()
+    base = replay.stack_params(pols)
+    if setting == "clock":
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(S, "EXACT_CLOCKS", True)
+            other = replay.stack_params(pols)
+        assert ieee.BITS + "t_pdt" in other
+        assert ieee.BITS + "t_pdt" not in base
+    elif setting == "x64":
+        with warnings.catch_warnings(), jax.enable_x64(False):
+            warnings.simplefilter("ignore")
+            other = replay.stack_params(pols)
+        assert other["t_pdt"].dtype == np.float32
+        assert base["t_pdt"].dtype == np.float64
+    else:
+        dev = jax.devices()[-1]
+        with jax.default_device(dev):
+            other = replay.stack_params(pols)
+        assert other["t_pdt"].devices() == {dev}
+    assert len(replay._PARAMS_CACHE) == 2
+    assert replay.stack_params(pols)["t_pdt"] is base["t_pdt"]
+
+
+def test_params_cache_stays_within_its_bound(monkeypatch):
+    """The least recently used group is dropped past the bound, and a hit
+    makes a group the most recent."""
+    monkeypatch.setattr(replay, "_PARAMS_CACHE_MAX", 3)
+    groups = [[Policy(kind="fixed", t_pdt=(i + 1) * 1e-6)] for i in range(6)]
+    replay._PARAMS_CACHE.clear()
+
+    def placed(g):
+        with span_log() as log:
+            replay.stack_params(g)
+        return log.counts.get("params_placed", 0)
+
+    assert [placed(g) for g in groups[:5]] == [1] * 5
+    assert len(replay._PARAMS_CACHE) == 3          # groups 2, 3, 4
+    assert placed(groups[2]) == 0                  # now the most recent
+    assert placed(groups[5]) == 1                  # drops group 3
+    assert len(replay._PARAMS_CACHE) == 3
+    assert [placed(groups[i]) for i in (2, 4, 5)] == [0, 0, 0]
+    assert placed(groups[3]) == 1
 
 
 # ---------------------------------------------------------------------------

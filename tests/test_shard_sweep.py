@@ -115,13 +115,14 @@ def test_replay_plans_sharded_bit_identical(topo):
 def test_sharded_replay_records_the_replay_layers():
     """The sharded branch opens the same dispatch / wait spans as the
     single-device engine and counts the padded (T, B) accumulators it
-    brings to host."""
+    brings to host, and the one placement of the group's parameters."""
     import math
     traces = _dc_traces(TINY)
     batch = stack_plans([compile_plan(t, TINY) for t in traces.values()],
                         list(traces))
     pols = [Policy(kind="fixed", t_pdt=float(t)) for t in (1e-6, 1e-5)]
     mesh = SS.mesh_for(batch.n_traces, len(pols))
+    replay._PARAMS_CACHE.clear()
     with span_log() as log:
         SS.replay_plans_sharded(batch, pols, PM, mesh)
     assert [s.name for s in log.spans] == ["repro.replay.dispatch",
@@ -130,7 +131,8 @@ def test_sharded_replay_records_the_replay_layers():
     dt, db = mesh.shape["trace"], mesh.shape["lane"]
     cells = (math.ceil(batch.n_traces / dt) * dt
              * math.ceil(len(pols) / db) * db)
-    assert log.counts == {"readback_bytes": 3 * 8 * cells}
+    assert log.counts == {"readback_bytes": 3 * 8 * cells,
+                          "params_placed": 1}
 
 
 @multi_device

@@ -355,9 +355,27 @@ def test_stream_counts_match_window_arrays(spied):
         assert row["counts"]["placed_bytes"] == plan_nbytes(ps[0]) > 0
 
 
+def test_stream_places_each_groups_params_once(tiny):
+    """``params_placed``: each static group of the replayed lanes places
+    its stacked parameters in the first window, and no later window
+    places any."""
+    from repro.core import replay
+    from repro.core.sweep import group_policies
+    spec = get_drift("drift-dc-diurnal").scaled(n_nodes=8, windows=2,
+                                                seed=9173)
+    replay._PARAMS_CACHE.clear()
+    out = advise_stream(spec, tiny, pool=POOL, budget_pct=0.1, pm=PM)
+    groups = len(group_policies({"baseline": Policy(kind="none"), **POOL}))
+    assert groups == 3                   # none, fixed, dual
+    assert [r["counts"]["params_placed"] for r in out["timeline"]] \
+        == [groups, 0]
+
+
 def test_stream_report_repeats_but_for_timing(spied, tiny):
     """A second run on the same stream, its caches emptied, reports the
-    same numbers in every field but the host times (and no compile)."""
+    same numbers in every field but the host times (and no compile) and
+    the parameter placements, which count the state of a cache that the
+    whole process shares."""
     from repro.streaming.drift import window_cache_clear
     from repro.traffic.plan import plan_cache_clear
     spec, first, _, _, _ = spied
@@ -367,7 +385,10 @@ def test_stream_report_repeats_but_for_timing(spied, tiny):
 
     def fields(out):
         return dict(out, timeline=[
-            {k: v for k, v in r.items() if k not in ("host_ms", "compiles")}
+            {k: v for k, v in dict(r, counts={
+                c: n for c, n in r["counts"].items()
+                if c != "params_placed"}).items()
+             if k not in ("host_ms", "compiles")}
             for r in out["timeline"]])
     assert fields(again) == fields(first)
     assert all(r["compiles"] == 0 for r in again["timeline"])
