@@ -59,19 +59,4 @@ def run(scale: str = "small"):
     bench("port_energy", lambda *a, **k: ops.port_energy_op(*a, **k),
           gaps, durs, tpdt, tail, t_w=DEEP_SLEEP.t_w, t_s=DEEP_SLEEP.t_s,
           check=dict_err)
-
-    # model-side kernels (reduced shapes; TPU program identical)
-    q = rng.normal(size=(2, 256, 8, 64)).astype(np.float32)
-    k = rng.normal(size=(2, 256, 2, 64)).astype(np.float32)
-    v = rng.normal(size=(2, 256, 2, 64)).astype(np.float32)
-    bench("flash_attn_fwd", lambda *a, **kw: ops.flash_attention_op(*a, **kw),
-          q, k, v, causal=True, block_q=64, block_kv=64, check=arr_err)
-    xs = rng.normal(size=(2, 256, 4, 32)).astype(np.float32)
-    dts = rng.uniform(0.001, 0.1, (2, 256, 4)).astype(np.float32)
-    Bc = rng.normal(size=(2, 256, 16)).astype(np.float32)
-    Cc = rng.normal(size=(2, 256, 16)).astype(np.float32)
-    A = (-rng.uniform(0.5, 4.0, 4)).astype(np.float32)
-    Dp = rng.normal(size=4).astype(np.float32)
-    bench("ssd_fwd", lambda *a, **kw: ops.ssd_op(*a, **kw),
-          xs, dts, Bc, Cc, A, Dp, chunk=64, check=pair_err)
     return rows

@@ -8,8 +8,8 @@ Every bench module exposes ``run(scale) -> list[Row]``.  ``scale``:
     default for ``python -m benchmarks.run`` so the suite finishes on CPU
     in minutes.
   * ``paper``  — the full §4 scenario (4160-node Megafly, 64-node apps).
-    Same code path, hours on CPU; numbers quoted in EXPERIMENTS.md
-    §Paper-validation were produced at this scale where noted.
+    Same code path, hours on CPU; the chip's numbers at this scale come
+    from ``bench/`` (PERF.md).
 
 Rows print as ``name,us_per_call,derived`` CSV (one per measured quantity).
 Modules may additionally expose ``n_policies(scale) -> int`` so the driver

@@ -29,7 +29,6 @@ MODULES = [
     ("perfbound", "benchmarks.bench_perfbound"),           # Figs 8/11/14/17
     ("decoupled", "benchmarks.bench_decoupled"),           # DESIGN.md §3
     ("kernels", "benchmarks.bench_kernels"),               # kernel parity
-    ("llm_traffic", "benchmarks.bench_llm_traffic"),       # beyond paper
     ("topology", "benchmarks.bench_topology"),             # beyond paper
     ("scenario_suite", "benchmarks.bench_scenario_suite"),  # beyond paper
     ("tuner", "benchmarks.bench_tuner"),                   # beyond paper

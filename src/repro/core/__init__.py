@@ -1,8 +1,7 @@
 """Core power-saving library (the paper's contribution).
 
 Times are float64 seconds: microsecond-scale transitions over 1000+ second
-simulations exceed f32 resolution, so the simulator enables x64.  Model code
-(`repro.models`) uses explicit f32/bf16 dtypes throughout and is unaffected.
+simulations exceed f32 resolution, so the simulator enables x64.
 """
 import jax
 

@@ -88,39 +88,3 @@ def port_energy_op(gaps, durs, tpdt, tail, t_dst=None, hold=None, *, t_w, t_s,
     return port_energy_pallas(f32(gaps), f32(durs), f32(tpdt), f32(tail),
                               **kw, interpret=interpret)
 
-
-def _llm_interpret() -> bool:
-    """Backend-chosen interpret mode of the seed LLM stack's kernels
-    (flash attention, SSD), which the simulator does not run."""
-    return jax.default_backend() != "tpu"
-
-
-@partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                   "block_kv", "use_ref"))
-def flash_attention_op(q, k, v, *, causal=True, window=None, block_q=512,
-                       block_kv=1024, use_ref=False):
-    """Differentiable flash attention (custom_vjp: Pallas fwd + FA2-style
-    two-pass Pallas bwd)."""
-    from repro.kernels.flash_attn import flash_attention
-    if use_ref:
-        return ref.flash_attention_ref(q, k, v, causal=causal,
-                                       window=window)
-    return flash_attention(q, k, v, causal, window, block_q, block_kv,
-                           _llm_interpret())
-
-
-@partial(jax.jit, static_argnames=("chunk", "use_ref"))
-def ssd_op(xs, dt, Bc, Cc, A, D, *, chunk=128, use_ref=False):
-    """Mamba2 SSD chunked forward (fresh sequence)."""
-    from repro.kernels.ssd import ssd_pallas
-    if use_ref:
-        return ref.ssd_ref(xs, dt, Bc, Cc, A, D, chunk=chunk)
-    return ssd_pallas(xs, dt, Bc, Cc, A, D, chunk=chunk,
-                      interpret=_llm_interpret())
-
-
-@partial(jax.jit, static_argnames=("chunk",))
-def ssd_op_vjp(xs, dt, Bc, Cc, A, D, *, chunk=128):
-    """Differentiable SSD: Pallas forward + oracle-recompute backward."""
-    from repro.kernels.ssd import ssd
-    return ssd(xs, dt, Bc, Cc, A, D, chunk, _llm_interpret())

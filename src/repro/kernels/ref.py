@@ -103,49 +103,3 @@ def port_energy_ref(gaps, durs, tpdt, tail, *, t_w, t_s,
     return {"time_wake": wake, "time_sleep": sleep, "time_sleep2": sleep2,
             "n_wake": nw, "hits": hit, "misses": miss, "n_deep": nd}
 
-
-def flash_attention_ref(q, k, v, *, causal=True, window=None):
-    """Oracle for the flash-attention kernel: direct softmax attention with
-    GQA head grouping, causal and sliding-window masks.  f32 math."""
-    import math
-    B, Sq, H, dh = q.shape
-    Skv, Hkv = k.shape[1], k.shape[2]
-    G = H // Hkv
-    scale = 1.0 / math.sqrt(dh)
-    qg = q.astype(jnp.float32).reshape(B, Sq, Hkv, G, dh)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32)) * scale
-    q_pos = jnp.arange(Sq)[:, None]
-    k_pos = jnp.arange(Skv)[None, :]
-    ok = jnp.ones((Sq, Skv), bool)
-    if causal:
-        ok &= k_pos <= q_pos
-    if window is not None:
-        ok &= q_pos - k_pos < window
-    s = jnp.where(ok, s, -0.7 * jnp.finfo(jnp.float32).max)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    return o.reshape(B, Sq, H, dh).astype(q.dtype)
-
-
-def ssd_ref(xs, dt, Bc, Cc, A, D, *, chunk=128):
-    """Oracle for the Mamba2 SSD kernel: direct (quadratic) evaluation.
-
-    xs: (B,S,H,P) f32; dt: (B,S,H); Bc/Cc: (B,S,N); A/D: (H,).
-    Returns (y (B,S,H,P) f32, h (B,H,N,P) f32)."""
-    B, S, H, P = xs.shape
-    xs32 = xs.astype(jnp.float32)
-    dt32 = dt.astype(jnp.float32)
-    dA = dt32 * A[None, None, :]                    # (B,S,H)
-    L = jnp.cumsum(dA, axis=1)
-    GB = jnp.einsum("btn,bsn->bts", Cc.astype(jnp.float32),
-                    Bc.astype(jnp.float32))         # (B,T,S)
-    decay = jnp.exp(L[:, :, None, :] - L[:, None, :, :])   # (B,T,S,H)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    M = GB[..., None] * decay * dt32[:, None, :, :]
-    M = jnp.where(causal[None, :, :, None], M, 0.0)
-    y = jnp.einsum("btsh,bshp->bthp", M, xs32)
-    y = y + xs32 * D[None, None, :, None]
-    # final state
-    w = jnp.exp(L[:, -1:, :] - L) * dt32            # (B,S,H)
-    h = jnp.einsum("bsh,bsn,bshp->bhnp", w, Bc.astype(jnp.float32), xs32)
-    return y, h

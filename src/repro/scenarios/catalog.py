@@ -2,8 +2,9 @@
 
 Twelve named scenarios spanning four families (see README for the table):
 
-* ``ml-*``  — training phases synthesized from ``repro.configs`` model
-  definitions through the DP/PP/TP collective schedule (``scenarios.ml``);
+* ``ml-*``  — training phases synthesized from published model shapes
+  (``scenarios.models``) through the DP/PP/TP collective schedule
+  (``scenarios.ml``);
 * ``hpc-*`` — stencil/halo and spectral BSP iteration structures;
 * ``dc-*``  — stochastic datacenter arrivals (Poisson / ON-OFF / incast /
   hotspot) — the whole family shares one plan shape by construction, so it
@@ -31,7 +32,7 @@ from repro.scenarios.spec import Scenario, params_of
 FAMILIES = ("ml", "hpc", "dc", "app")
 
 CATALOG = [
-    # -- ML training (from configs/*) -------------------------------------
+    # -- ML training (shapes from scenarios/models.py) -------------------
     Scenario(
         "ml-qwen2-1.5b", "ml", "ml_training", 16, seed=11,
         params=params_of(arch="qwen2-1.5b", iters=2),

@@ -1,6 +1,6 @@
-"""ML-training scenario synthesis from the repo's model configs.
+"""ML-training scenario synthesis from published model shapes.
 
-Lowers a :class:`~repro.configs.base.ModelConfig` plus a 3D parallelism
+Lowers a :class:`~repro.scenarios.models.ModelConfig` plus a 3D parallelism
 grid (DP x PP x TP) into the phase-structured trace language: per-stage
 forward/backward compute, fused tensor-parallel activation all-reduces,
 pipeline point-to-point activation/gradient transfers, and bucketed
@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.configs.base import get_config
+from repro.scenarios.models import get_model
 from repro.scenarios.spec import builder, rng
 from repro.traffic import collectives as C
 from repro.traffic.generators import allocate
@@ -66,7 +66,7 @@ def ml_training(topo, n_nodes, seed, arch, iters=2, dp=0, tp=0, pp=0,
                 mapping="linear"):
     """One trace = ``iters`` training steps of ``arch`` on a DP x PP x TP
     grid (unset dims derived from ``n_nodes``, see ``derive_grid``)."""
-    cfg = get_config(arch)
+    cfg = get_model(arch)
     dp, tp, pp = derive_grid(n_nodes, dp, tp, pp)
     nodes = allocate(topo, n_nodes, mapping, seed)
     t = Trace(nodes=nodes, name=f"ml-{arch}")
@@ -159,7 +159,7 @@ def moe_training(topo, n_nodes, seed, arch, iters=2, layer_groups=4,
     dense symmetric bursts separated by compute gaps that distinguish MoE
     traffic from the dense-model pipeline of ``ml_training``.
     """
-    cfg = get_config(arch)
+    cfg = get_model(arch)
     assert cfg.num_experts > 0, f"{arch} is not a MoE config"
     nodes = allocate(topo, n_nodes, mapping, seed)
     t = Trace(nodes=nodes, name=f"moe-{arch}")

@@ -60,7 +60,7 @@ class Scenario:
     """One named, parametric workload (a catalog entry).
 
     ``family`` groups catalog listings: ``ml`` (training phases from
-    ``repro.configs``), ``hpc`` (stencil / BSP iteration structures),
+    ``scenarios.models``), ``hpc`` (stencil / BSP iteration structures),
     ``dc`` (stochastic datacenter arrivals), ``app`` (the paper's §4
     application generators).
     """

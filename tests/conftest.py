@@ -1,5 +1,5 @@
 """Shared fixtures.  Importing repro.core enables jax x64 (the simulator
-needs it); model tests use explicit dtypes and are unaffected."""
+needs it)."""
 import numpy as np
 import pytest
 

@@ -346,179 +346,3 @@ def test_compiled_kernel_off_tpu_raises(kernel):
     jax.block_until_ready(call(interpret=True))
     jax.block_until_ready(call(use_ref=True))
 
-
-# ---------------------------------------------------------------------------
-# flash_attention
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("B,Sq,H,Hkv,dh,causal,window", [
-    (2, 128, 4, 2, 32, True, None),
-    (1, 96, 4, 4, 16, True, None),      # ragged seq vs 32-blocks, MHA
-    (2, 64, 8, 2, 32, False, None),     # non-causal (encoder)
-    (1, 128, 4, 2, 32, True, 48),       # sliding window (gemma3-style)
-    (1, 64, 8, 1, 16, True, None),      # MQA
-])
-def test_flash_attention_matches_ref(B, Sq, H, Hkv, dh, causal, window, rng):
-    q = jnp.asarray(rng.normal(size=(B, Sq, H, dh)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, Sq, Hkv, dh)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, Sq, Hkv, dh)), jnp.float32)
-    out = ops.flash_attention_op(q, k, v, causal=causal, window=window,
-                                 block_q=32, block_kv=32)
-    want = ops.flash_attention_op(q, k, v, causal=causal, window=window,
-                                  use_ref=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_dtypes(dtype, rng):
-    q = jnp.asarray(rng.normal(size=(1, 64, 4, 32)), dtype)
-    k = jnp.asarray(rng.normal(size=(1, 64, 2, 32)), dtype)
-    v = jnp.asarray(rng.normal(size=(1, 64, 2, 32)), dtype)
-    out = ops.flash_attention_op(q, k, v, block_q=32, block_kv=32)
-    want = ops.flash_attention_op(q, k, v, use_ref=True)
-    assert out.dtype == dtype
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(want, np.float32),
-        rtol=2e-2, atol=2e-2)
-
-
-def test_flash_attention_in_model_forward(rng):
-    """attn_impl='pallas' produces the same logits as the 'jax' path."""
-    import dataclasses
-    from repro.configs.base import get_config
-    from repro.models import model as M
-    cfg = get_config("qwen2-1.5b").smoke()
-    cfg_j = dataclasses.replace(cfg, attn_impl="jax",
-                                attn_direct_max_seq=1)  # force chunked
-    cfg_p = dataclasses.replace(cfg, attn_impl="pallas",
-                                attn_chunk_q=16, attn_chunk_kv=16)
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 32)),
-                                   jnp.int32)}
-    a = M.forward(params, batch, cfg_j, mode="train")["logits"]
-    b = M.forward(params, batch, cfg_p, mode="train")["logits"]
-    np.testing.assert_allclose(np.asarray(a, np.float32),
-                               np.asarray(b, np.float32),
-                               rtol=1e-3, atol=1e-3)
-
-
-@pytest.mark.parametrize("B,S,H,Hkv,dh,causal,window", [
-    (2, 96, 4, 2, 32, True, None),
-    (2, 64, 8, 2, 32, False, None),
-    (1, 96, 4, 2, 32, True, 40),
-    (1, 64, 8, 1, 16, True, None),
-])
-def test_flash_attention_backward(B, S, H, Hkv, dh, causal, window, rng):
-    """custom_vjp (Pallas fwd + FA2 two-pass Pallas bwd) matches autodiff
-    of the reference to f32 precision."""
-    q = jnp.asarray(rng.normal(size=(B, S, H, dh)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, Hkv, dh)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, Hkv, dh)), jnp.float32)
-
-    def loss(fn):
-        return lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32)))
-
-    gk = jax.grad(loss(lambda *a: ops.flash_attention_op(
-        *a, causal=causal, window=window, block_q=32, block_kv=32)),
-        argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss(lambda *a: ops.flash_attention_op(
-        *a, causal=causal, window=window, use_ref=True)),
-        argnums=(0, 1, 2))(q, k, v)
-    for a, b, name in zip(gk, gr, "q k v".split()):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-4,
-                                   err_msg=f"d{name}")
-
-
-def test_flash_attention_train_step_end_to_end(rng):
-    """A full train step through attn_impl='pallas' (kernel fwd+bwd) moves
-    params and matches the pure-JAX path's loss."""
-    import dataclasses
-    from repro.configs.base import get_config
-    from repro.training.loop import init_train_state, make_train_step
-    cfg = dataclasses.replace(get_config("qwen2-1.5b").smoke(),
-                              attn_impl="pallas", attn_chunk_q=16,
-                              attn_chunk_kv=16)
-    cfg_j = dataclasses.replace(cfg, attn_impl="jax")
-    state = init_train_state(cfg, jax.random.PRNGKey(0))
-    batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 32)),
-                                   jnp.int32),
-             "labels": jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 32)),
-                                   jnp.int32)}
-    s1, m1 = jax.jit(make_train_step(cfg))(state, batch)
-    s2, m2 = jax.jit(make_train_step(cfg_j))(state, batch)
-    np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]),
-                               rtol=1e-4)
-    np.testing.assert_allclose(float(m1["grad_norm"]),
-                               float(m2["grad_norm"]), rtol=1e-3)
-
-
-# ---------------------------------------------------------------------------
-# ssd (Mamba2 state-space dual)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("B,S,H,P,N,Q", [
-    (2, 64, 3, 16, 8, 16),
-    (1, 40, 2, 8, 4, 16),       # ragged chunks
-    (2, 32, 4, 32, 16, 32),     # single chunk
-])
-def test_ssd_matches_ref(B, S, H, P, N, Q, rng):
-    xs = jnp.asarray(rng.normal(size=(B, S, H, P)), jnp.float32)
-    dt = jnp.asarray(rng.uniform(0.001, 0.1, (B, S, H)), jnp.float32)
-    Bc = jnp.asarray(rng.normal(size=(B, S, N)), jnp.float32)
-    Cc = jnp.asarray(rng.normal(size=(B, S, N)), jnp.float32)
-    A = jnp.asarray(-rng.uniform(0.5, 4.0, H), jnp.float32)
-    D = jnp.asarray(rng.normal(size=H), jnp.float32)
-    yk, hk = ops.ssd_op(xs, dt, Bc, Cc, A, D, chunk=Q)
-    yr, hr = ops.ssd_op(xs, dt, Bc, Cc, A, D, use_ref=True)
-    np.testing.assert_allclose(np.asarray(yk), np.asarray(yr),
-                               rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(hk), np.asarray(hr),
-                               rtol=1e-4, atol=1e-5)
-
-
-def test_ssd_gradients(rng):
-    xs = jnp.asarray(rng.normal(size=(1, 32, 2, 8)), jnp.float32)
-    dt = jnp.asarray(rng.uniform(0.001, 0.1, (1, 32, 2)), jnp.float32)
-    Bc = jnp.asarray(rng.normal(size=(1, 32, 4)), jnp.float32)
-    Cc = jnp.asarray(rng.normal(size=(1, 32, 4)), jnp.float32)
-    A = jnp.asarray([-1.0, -2.0], jnp.float32)
-    D = jnp.asarray([0.5, 0.2], jnp.float32)
-
-    def loss(fn):
-        return lambda *a: jnp.sum(jnp.sin(fn(*a)[0]))
-
-    gk = jax.grad(loss(lambda *a: ops.ssd_op_vjp(*a, chunk=16)),
-                  argnums=(0, 1, 2, 3))(xs, dt, Bc, Cc, A, D)
-    gr = jax.grad(loss(lambda *a: ops.ssd_op(*a, use_ref=True)),
-                  argnums=(0, 1, 2, 3))(xs, dt, Bc, Cc, A, D)
-    for a, b in zip(gk, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-5)
-
-
-def test_ssd_in_mamba_block(rng):
-    """ssm_impl='pallas' mamba2_block matches the chunked-jax path on a
-    fresh sequence, forward and train-gradients."""
-    import dataclasses
-    from repro.configs.base import get_config
-    from repro.models import layers as L
-    cfg = get_config("zamba2-7b").smoke()
-    cfg_p = dataclasses.replace(cfg, ssm_impl="pallas")
-    p = L.mamba2_params(jax.random.PRNGKey(0), cfg)
-    x = jnp.asarray(rng.normal(size=(2, 24, cfg.d_model)), jnp.float32)
-    y0, (c0, h0) = L.mamba2_block(x, p, cfg)
-    y1, (c1, h1) = L.mamba2_block(x, p, cfg_p)
-    np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
-                               rtol=2e-3, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(h0), np.asarray(h1),
-                               rtol=2e-3, atol=2e-4)
-    g0 = jax.grad(lambda x: jnp.sum(L.mamba2_block(x, p, cfg)[0]
-                                    .astype(jnp.float32)))(x)
-    g1 = jax.grad(lambda x: jnp.sum(L.mamba2_block(x, p, cfg_p)[0]
-                                    .astype(jnp.float32)))(x)
-    np.testing.assert_allclose(np.asarray(g0), np.asarray(g1),
-                               rtol=5e-3, atol=5e-4)

@@ -1,119 +1,14 @@
-"""Launcher-layer integration: train loop with resume, power advisor."""
-import json
-
-import jax
-import numpy as np
+"""Power advisor front doors: a catalog scenario name and a budget in, a
+recommended policy and its frontier out."""
 import pytest
 
-from repro.configs.base import get_config
-from repro.launch.power_advisor import (DEFAULT_POLICIES, advise,
-                                        llm_trace_from_cell)
-from repro.launch.train import train
+from repro.launch.power_advisor import advise_scenario
 from repro.topology.megafly import small_topology
-
-CFG = get_config("qwen2-1.5b").smoke()
-
-
-def test_train_runs_and_checkpoints(tmp_path):
-    _, losses = train(CFG, steps=6, seq_len=16, global_batch=4,
-                      ckpt_dir=tmp_path, save_every=3, log_every=100,
-                      log=lambda *a: None)
-    assert len(losses) == 6
-    assert all(np.isfinite(l) for l in losses)
-    steps = sorted(int(p.name[5:]) for p in tmp_path.iterdir()
-                   if p.name.startswith("step_"))
-    assert steps and steps[-1] == 6
-
-
-def test_train_resume_reproduces_stream(tmp_path):
-    """Stop at step 4, resume to 8 == one uninterrupted 8-step run."""
-    _, l_a1 = train(CFG, steps=4, seq_len=16, global_batch=4,
-                    ckpt_dir=tmp_path, save_every=4, log_every=100,
-                    log=lambda *a: None)
-    _, l_a2 = train(CFG, steps=8, seq_len=16, global_batch=4,
-                    ckpt_dir=tmp_path, save_every=100, resume=True,
-                    log_every=100, log=lambda *a: None)
-    _, l_b = train(CFG, steps=8, seq_len=16, global_batch=4,
-                   log_every=100, log=lambda *a: None)
-    np.testing.assert_allclose(l_a1 + l_a2, l_b, rtol=1e-4)
-
-
-FAKE_CELL = {
-    "arch": "fake-1b", "shape": "train_4k", "mesh": "16x16",
-    "n_devices": 64, "status": "ok",
-    "cost": {"flops": 1e12},
-    "collectives": {
-        "per_op": {"all-reduce": 3e8, "all-gather": 1e8},
-        "per_axis": {"tp": 2.5e8, "dp": 1.5e8},
-        "while_trip_counts": {"body": 4},
-    },
-}
-
-
-def test_llm_trace_structure(topo):
-    tr = llm_trace_from_cell(FAKE_CELL, topo, n_steps=2, tp_degree=16)
-    assert len(tr.nodes) == 64
-    # per step: 4 layers x (compute + TP rounds) + DP rounds
-    msgs = tr.n_messages
-    assert msgs > 0
-    # TP allreduce within 16-node groups: 2*log2(16) rounds of 16 nodes x 4
-    # groups x 4 layers x 2 steps + DP rounds
-    assert tr.total_bytes > 0
-
-
-def test_advise_from_fake_dryrun(tmp_path, topo):
-    p = tmp_path / "fake-1b__train_4k__pod1.json"
-    p.write_text(json.dumps(FAKE_CELL))
-    out = advise("fake-1b", "train_4k", topo=topo, dryrun_dir=tmp_path,
-                 n_steps=1, max_overhead_pct=5.0)
-    assert out["recommended"] is not None
-    assert set(out["table"]) == {"baseline", *DEFAULT_POLICIES}
-    base = out["table"]["baseline"]
-    assert base["exec_overhead_pct"] == 0.0
-    tp, dp = out["tp_dp_bytes"]
-    assert tp == 2.5e8 and dp == 1.5e8
-
-
-def test_advise_empty_budget_falls_back_to_baseline(tmp_path, topo):
-    """No policy fits an impossible budget: the advisor answers the
-    always-on baseline (like ``frontier.budget_winner``), never None."""
-    p = tmp_path / "fake-1b__train_4k__pod1.json"
-    p.write_text(json.dumps(FAKE_CELL))
-    out = advise("fake-1b", "train_4k", topo=topo, dryrun_dir=tmp_path,
-                 n_steps=1, max_overhead_pct=-1.0)
-    assert out["recommended"] == "baseline"
-    assert out["table"]["baseline"]["exec_overhead_pct"] == 0.0
-
-
-def test_llm_trace_small_cell_guards_degenerate_split(topo):
-    """n_devices < tp_degree (e.g. an 8-device cell with the default
-    tp_degree=16): the strided DP split used to produce EMPTY node groups
-    and TP allreduce over a non-2**k remainder; the clamp keeps every
-    emitted group a power of two >= 2."""
-    for n_dev in (8, 12):
-        cell = dict(FAKE_CELL, n_devices=n_dev)
-        tr = llm_trace_from_cell(cell, topo, n_steps=1, tp_degree=16)
-        assert len(tr.nodes) == n_dev
-        assert tr.n_messages > 0 and tr.total_bytes > 0
-        for step in tr.steps:
-            if step.msgs is not None and len(step.msgs):
-                assert (step.msgs[:, 0] != step.msgs[:, 1]).all()
-    # a 1-device cell has no collective partners at all: compute-only trace
-    tr = llm_trace_from_cell(dict(FAKE_CELL, n_devices=1), topo, n_steps=1)
-    assert tr.n_messages == 0
-
-
-def test_advise_rejects_failed_cell(tmp_path):
-    p = tmp_path / "bad__train_4k__pod1.json"
-    p.write_text(json.dumps({"status": "failed", "error": "x"}))
-    with pytest.raises(ValueError):
-        advise("bad", "train_4k", dryrun_dir=tmp_path)
 
 
 def test_advise_scenario_recommends_within_budget():
     """The catalog front door of the auto-tuner: a scenario name + budget
     in, a budget-respecting policy recommendation + frontier out."""
-    from repro.launch.power_advisor import advise_scenario
     from repro.tuning import tiny_space
     tiny = small_topology(n_groups=3, leaves=2, spines=2, nodes_per_leaf=2)
     out = advise_scenario("dc-poisson", budget_pct=1.0, topo=tiny,
@@ -129,6 +24,5 @@ def test_advise_scenario_recommends_within_budget():
 
 
 def test_advise_scenario_rejects_unknown_name():
-    from repro.launch.power_advisor import advise_scenario
     with pytest.raises(KeyError, match="unknown scenario"):
         advise_scenario("no-such-workload")

@@ -11,6 +11,7 @@ from repro.core.eee import Policy, PowerModel
 from repro.core.instrument import count_compiles
 from repro.core.sweep import group_policies, sweep_policies, sweep_scenarios
 from repro.scenarios.ml import derive_grid
+from repro.scenarios.models import get_model
 from repro.topology.megafly import small_topology
 from repro.traffic import plan as P
 
@@ -169,6 +170,18 @@ def test_ml_grid_derivation():
         derive_grid(12)
     with pytest.raises(AssertionError):
         derive_grid(16, dp=3, tp=2, pp=2)
+
+
+@pytest.mark.parametrize("arch,layer,embed", [
+    ("qwen2-1.5b", 46792704, 233373696),
+    ("gemma3-4b", 68157440, 671088640),
+    ("qwen3-moe-30b-a3b", 623116288, 311164928),
+])
+def test_ml_config_param_counts(arch, layer, embed):
+    """The ml-* traces are sized from these counts: pin them."""
+    cfg = get_model(arch)
+    assert (cfg.layer_param_count(), cfg.embed_param_count()) == \
+        (layer, embed)
 
 
 # ---------------------------------------------------------------------------
