@@ -160,6 +160,30 @@ def _initial_tpdt(policy, params=None):
 # ---------------------------------------------------------------------------
 
 
+def take_rows(x, lp):
+    """``x[lp]``: the rows of a per-link table that one message's hop
+    rows ``lp`` name, read as one dynamic slice a row.
+
+    On the TPU a gather of whole rows asks for a tiling in which each row
+    is contiguous, and XLA re-lays the whole table out for it, the
+    (links x bins) histograms included, on every message; a dynamic slice
+    reads the row's tiles where they lie.  More rows than a route has
+    hops (a wave's, or every link's) stay one gather.  Either way the
+    values are the table's."""
+    if lp.ndim != 1 or lp.shape[0] > MAXH:
+        return x[lp]
+    return _row_slices(x, lp)
+
+
+@jax.jit
+def _row_slices(x, lp):
+    """The slices of ``take_rows``, jitted so that a replay program traces
+    and batches them once per table shape, not at each of a message's
+    reads; XLA inlines the call, so the compiled program is the same."""
+    return jnp.stack([lax.dynamic_index_in_dim(x, lp[k], keepdims=False)
+                      for k in range(lp.shape[0])])
+
+
 def record_gaps(st, lp, gap, t_now, active, policy, params=None,
                 gap_bits=None):
     """Insert inactivity gaps.  lp,gap,t_now,active: (K,) or (m, H);
@@ -227,9 +251,9 @@ def record_gaps(st, lp, gap, t_now, active, policy, params=None,
     if policy.hist_mode == "self_clear":
         clear = active & (total[lp] >= p["hist_clear_n"])
         st["counts"] = st["counts"].at[lp].set(
-            jnp.where(clear[..., None], 0.0, st["counts"][lp]))
+            jnp.where(clear[..., None], 0.0, take_rows(st["counts"], lp)))
         st["sums"] = st["sums"].at[lp].set(
-            jnp.where(clear[..., None], 0.0, st["sums"][lp]))
+            jnp.where(clear[..., None], 0.0, take_rows(st["sums"], lp)))
         st["total"] = st["total"].at[lp].set(
             jnp.where(clear, 0, st["total"][lp]))
         st["win_start"] = st["win_start"].at[lp].set(
@@ -366,7 +390,8 @@ def compute_tdst(st, lp, tpdt_new, policy, params=None):
     freshly selected ``tpdt_new``.  (K,) -> (K,)."""
     p = _params(policy, params)
     r_star = jnp.broadcast_to(deep_breakeven(p), lp.shape)
-    return tdst_select(st["counts"][lp], st["sums"][lp], tpdt_new, r_star,
+    return tdst_select(take_rows(st["counts"], lp),
+                       take_rows(st["sums"], lp), tpdt_new, r_star,
                        st["total"][lp], policy, p)
 
 
@@ -376,12 +401,12 @@ def compute_tpdt_tdst(st, lp, t_now, t_w, policy, params=None):
     demotion-threshold selection — the per-message hot path would
     otherwise do both twice.  Returns (t_pdt, t_dst), each (K,)."""
     p = _params(policy, params)
-    counts = st["counts"][lp]
-    sums = st["sums"][lp]
+    counts = take_rows(st["counts"], lp)
+    sums = take_rows(st["sums"], lp)
     total = st["total"][lp]
     ccum = _suffix_sum(counts)
     X = jnp.maximum(t_now - st["win_start"][lp], 0.0)
-    l = l_factor(st["hops"][lp], p["bound"])
+    l = l_factor(take_rows(st["hops"], lp), p["bound"])
     N = l * X / t_w
     t = tpdt_select(counts, sums, N, total, policy, p, ccum=ccum)
     r_star = jnp.broadcast_to(deep_breakeven(p), lp.shape)
@@ -435,8 +460,8 @@ def forecast_update(st, lp, gap, active, policy, params=None):
         first, gap,
         jnp.where(obs, (1.0 - w) * ewma_old + w * gap, ewma_old))
 
-    counts = st["counts"][lp]
-    sums = st["sums"][lp]
+    counts = take_rows(st["counts"], lp)
+    sums = take_rows(st["sums"], lp)
     mass = counts.sum(-1)
     j = jnp.argmax(counts, axis=-1)
     cj = jnp.take_along_axis(counts, j[..., None], -1)[..., 0]
@@ -470,16 +495,16 @@ def pbc_cf(reg, ratio_log, n_seen, policy):
 def compute_tpdt(st, lp, t_now, t_w, policy, params=None):
     """Recalculate t_PDT for link rows ``lp`` at time ``t_now``.  (K,)->(K,)."""
     p = _params(policy, params)
-    counts = st["counts"][lp]
-    sums = st["sums"][lp]
+    counts = take_rows(st["counts"], lp)
+    sums = take_rows(st["sums"], lp)
     total = st["total"][lp]
     X = jnp.maximum(t_now - st["win_start"][lp], 0.0)
-    l = l_factor(st["hops"][lp], p["bound"])
+    l = l_factor(take_rows(st["hops"], lp), p["bound"])
     N = l * X / t_w
     t = tpdt_select(counts, sums, N, total, policy, p)
     if policy.kind == "perfbound_correct":
-        cf = pbc_cf(st["reg"][lp], st["ratio_log"][lp], st["n_seen"][lp],
-                    policy)
+        cf = pbc_cf(st["reg"][lp], take_rows(st["ratio_log"], lp),
+                    st["n_seen"][lp], policy)
         if policy.cf_mode == "uplift":
             t = t * (1.0 + cf)
         else:

@@ -13,7 +13,9 @@ accumulators) entirely on device across the whole trace:
   * compute advances and barriers are **scan-step branches**: a dense
     per-step clock delta plus a masked participant-max select;
   * message-less steps skip the message machinery through a ``lax.cond``
-    on the plan's per-step ``has_msgs`` flag.
+    on the plan's per-step ``has_msgs`` flag (over a stack of traces,
+    whether any trace sends: ``_trace_controls``), except in the
+    ``prefix`` executor, whose loop runs no trip on such a step.
 
 The serial engine is the B=1 case of the batched one: ``policies`` lanes
 share a canonical static proto (``eee.canonical_proto``) and read their
@@ -493,10 +495,19 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
             return net, ready, lat_sum, lat_max, out[2]
         return net, ready, lat_sum, lat_max
 
-    def run(nets, params, ready, lat_sum, lat_max, part_mask, xs):
+    def run(nets, params, ready, lat_sum, lat_max, part_mask, xs, ctl=None):
+        """``ctl`` holds each step's control values, the ``prefix`` loop's
+        bound ``live`` and the other executors' message branch predicate
+        ``has_msgs``:
+        the segment's own unless a caller that vmaps ``run`` over traces
+        hands in their reduction (``_trace_controls``), which the vmap
+        leaves unbatched."""
         B = ready.shape[0]
+        if ctl is None:
+            ctl = {k: xs[k] for k in ("live", "has_msgs") if k in xs}
 
-        def step(carry, x):
+        def step(carry, xc):
+            x, c = xc
             nets, ready, lat_sum, lat_max = carry
             ready = C.add(ready, x[delta_key][None])
             ev = None
@@ -511,7 +522,7 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
                         extra = _conflicts(x["links"], x["nhops"],
                                            x["valid"])
                     elif mode == "prefix":
-                        extra = x["live"]
+                        extra = c["live"]
                     return jax.vmap(_lane,
                                     in_axes=(0, 0, 0, 0, 0, None, None))(
                         nets, params, ready, ls, lm, mx, extra)
@@ -526,8 +537,12 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
                         jnp.zeros((B, cap, H), jnp.float64),
                         jnp.zeros((B, cap, H), bool)),)
 
-                out = lax.cond(x["has_msgs"], do, skip,
-                               (nets, ready, lat_sum, lat_max))
+                # a step of the prefix executor with no message runs an
+                # empty loop, so it takes no branch: on the TPU the
+                # conditional cost more than such steps save
+                ops = (nets, ready, lat_sum, lat_max)
+                out = do(ops) if mode == "prefix" else lax.cond(
+                    c["has_msgs"], do, skip, ops)
                 if collect_events:
                     nets, ready, lat_sum, lat_max, ev = out
                 else:
@@ -537,7 +552,7 @@ def _make_run(proto: Policy, pm: PowerModel, n_links: int, cap: int,
             ready = jnp.where(x["barrier"] & part_mask, rmax[:, None], ready)
             return (nets, ready, lat_sum, lat_max), ev
 
-        return lax.scan(step, (nets, ready, lat_sum, lat_max), xs)
+        return lax.scan(step, (nets, ready, lat_sum, lat_max), (xs, ctl))
 
     # the compiled program (and its device trace events) reads
     # ``jit_replay_<mode>``: the executor, whichever runner jits it
@@ -555,20 +570,49 @@ def _segment_runner(proto: Policy, pm: PowerModel, n_links: int, cap: int,
                   needs_sort))
 
 
+def _trace_controls(xs) -> dict:
+    """Each step's control values over a stack of traces: the most live
+    messages of any trace, and whether any trace sends.
+
+    A step's slots past a trace's own live count are padding, masked
+    no-ops on the dummy rows, and a step of all padding leaves ``ready``,
+    ``lat_sum`` and ``lat_max`` as they were, so each trace's results keep
+    their bits.  Handed to ``run`` unbatched, they keep the ``prefix``
+    loop and the message branch plain control flow: vmapped over batched
+    controls, the loop would select every carry leaf between old and new
+    values each iteration and the branch would run both sides, so XLA
+    would copy the PerfBound histograms before every in-place scatter."""
+    if "live" not in xs:
+        return {}
+    return {"live": xs["live"].max(0), "has_msgs": xs["has_msgs"].any(0)}
+
+
+def trace_runner(run):
+    """``run`` (``_make_run``'s per-trace program) vmapped over a leading
+    T trace axis, fed the traces' reduced step controls
+    (``_trace_controls``).  ``params`` is shared across traces (in_axes
+    None) — every trace lane replays the same stacked policy group — while
+    the carry, participant mask and segment arrays are per-trace."""
+    vrun = jax.vmap(run, in_axes=(0, None, 0, 0, 0, 0, 0, None))
+
+    def traces(nets, params, ready, lat_sum, lat_max, part_mask, xs):
+        return vrun(nets, params, ready, lat_sum, lat_max, part_mask, xs,
+                    _trace_controls(xs))
+
+    traces.__name__ = traces.__qualname__ = run.__name__
+    return traces
+
+
 @lru_cache(maxsize=None)
 def _multi_segment_runner(proto: Policy, pm: PowerModel, n_links: int,
                           cap: int, mode: str = "scan",
                           needs_sort: bool = True):
     """The multi-trace runner: the per-trace program vmapped over a leading
-    T axis.  ``params`` is shared across traces (in_axes None) — every
-    trace lane replays the same stacked policy group — while the carry,
-    participant mask and segment arrays are per-trace.  Retraces per
-    (T, S, B) shape; programs are shared across stack groups with equal
-    segment shapes."""
+    T axis (``trace_runner``).  Retraces per (T, S, B) shape; programs are
+    shared across stack groups with equal segment shapes."""
     run = _make_run(proto, pm, n_links, cap, collect_events=False,
                     mode=mode, needs_sort=needs_sort)
-    return partial(jax.jit, donate_argnums=(0, 2, 3, 4))(
-        jax.vmap(run, in_axes=(0, None, 0, 0, 0, 0, 0)))
+    return partial(jax.jit, donate_argnums=(0, 2, 3, 4))(trace_runner(run))
 
 
 # ---------------------------------------------------------------------------

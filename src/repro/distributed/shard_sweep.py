@@ -186,14 +186,14 @@ def _sharded_runner(proto: Policy, pm: PowerModel, n_links: int, cap: int,
                     mesh: Mesh, mode: str = "scan",
                     needs_sort: bool = True):
     """``_make_run`` vmapped over T and shard_mapped over the mesh — the
-    same scan program as ``replay._multi_segment_runner``, tiled.  Every
+    same scan program as ``replay._multi_segment_runner``, tiled, each
+    device reducing the step controls over its own traces.  Every
     input/output is tile-local (``check_vma=False``: there is no
     replication to verify and no collective in the program).  Carry
     buffers donate, exactly like the single-device runners."""
     run = replay._make_run(proto, pm, n_links, cap, collect_events=False,
                            mode=mode, needs_sort=needs_sort)
-    vrun = jax.vmap(run, in_axes=(0, None, 0, 0, 0, 0, 0))
-    sm = jax.shard_map(vrun, mesh=mesh,
+    sm = jax.shard_map(replay.trace_runner(run), mesh=mesh,
                        in_specs=(SP_TB, SP_B, SP_TB, SP_TB, SP_TB, SP_T,
                                  SP_T),
                        out_specs=(SP_TB, None), check_vma=False)

@@ -391,6 +391,76 @@ def test_cached_params_replay_is_bit_identical(group_replays, group):
         == runs["cold"]["out"]
 
 
+#: a float-clock group and the three PerfBound kinds on exact clocks, as
+#: the PerfBound stream replays them
+STACK_GROUPS = {
+    "fixed": GROUPS["fixed"],
+    "perfbound": GROUPS["perfbound"],
+    "perfbound_correct": (Policy(kind="perfbound_correct", bound=0.01,
+                                 hist_mode="keep_all",
+                                 sleep_state="deep_sleep"),),
+    "perfbound_dual": (Policy(kind="perfbound_dual", bound=0.01,
+                              hist_mode="keep_all", sleep_state="fast_wake",
+                              deep_state="deep_sleep"),),
+}
+
+
+def _ragged_traces():
+    """Two traces of one plan shape whose steps send 8/3/0/5 and 2/0/6/5
+    messages: the live counts differ in every step but the last, and in
+    two steps only one trace sends."""
+    nodes = np.arange(8, dtype=np.int64)
+    out = []
+    for name, sends in (("a", (8, 3, 0, 5)), ("b", (2, 0, 6, 5))):
+        tr = Trace(nodes=nodes, name=name)
+        for r, m in enumerate(sends):
+            tr.compute(1e-5 * (r + 1))
+            if m:
+                tr.messages([[int(i), int((i + 1 + r) % 8), 2048 << r]
+                             for i in range(m)], barrier=r == 3)
+        out.append(tr)
+    return out
+
+
+@pytest.mark.parametrize("group", sorted(STACK_GROUPS))
+def test_trace_stack_replays_each_trace_as_alone(group, monkeypatch):
+    """A two-trace stack on the ``prefix`` executor, whose loop runs the
+    most live messages of either trace, on steps where only one of them
+    sends too: each trace's ``t_end``, ``lat_sum``, ``lat_max``
+    and ``lane_sums`` equal, byte for byte, its own single-trace
+    replay's."""
+    pols = list(STACK_GROUPS[group])
+    if group != "fixed":
+        monkeypatch.setattr(S, "EXACT_CLOCKS", True)
+    want_clock = ieee.FloatClock if group == "fixed" else ieee.ExactClock
+    assert S.clock_of(pols[0]) is want_clock
+    topo = TOPOS["fattree"]
+    plans = [P.compile_plan(tr, topo) for tr in _ragged_traces()]
+    batch = P.stack_plans(plans, ["a", "b"])
+    seg = next(s for s in batch.segments if s.cap)
+    live = np.asarray(seg.xs["live"])
+    assert (live[0] != live[1]).any() and (live.min(0) == 0).any() \
+        and (live.max(0) > 0).all()
+    _clear_runners()
+    replay._segment_runner.cache_clear()
+    try:
+        with replay.wavefront_mode("prefix"):
+            nets, t_end, lat_sum, lat_max = replay.replay_plans(
+                batch, pols, PM)
+            lanes = S.lane_sums(nets, t_end, pols, topo.n_links)
+            for t, plan in enumerate(plans):
+                nets1, t1, ls1, lm1, _ = replay.replay_plan(plan, pols, PM)
+                lanes1 = S.lane_sums(nets1, t1, pols, topo.n_links)
+                assert [_bits(x[t]) for x in (t_end, lat_sum, lat_max,
+                                              lanes)] \
+                    == [_bits(x) for x in (t1, ls1, lm1, lanes1)], \
+                    f"{group}: trace {t}"
+                assert (t1 > 0).all() and (ls1 > 0).all()
+    finally:
+        _clear_runners()
+        replay._segment_runner.cache_clear()
+
+
 def test_params_cache_keys_on_the_numbers():
     """Two groups of one static key but different numbers are two entries;
     a hit returns a fresh dict over the same arrays."""

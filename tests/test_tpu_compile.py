@@ -148,3 +148,57 @@ def test_exact_clock_segment_compiles(one_chip, monkeypatch):
             f.cache_clear()
     assert "u32" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def test_exact_clock_histograms_stay_in_place(one_chip, monkeypatch):
+    """The stream's prefix program of a keep_all PerfBound lane on exact
+    clocks, compiled for v5e on a k = 8 fat-tree: no computation inside
+    it copies or selects a whole (links x bins) histogram.  The loop bound
+    stays off the trace axis and the steps take no branch, so no select
+    keeps the old histogram alive, and a message's rows are read in place
+    (``perfbound.take_rows``), so no gather re-lays the table out."""
+    import re
+
+    import numpy as np
+
+    from repro.core import simulator as S
+    from repro.topology.fattree import FatTree
+    from repro.traffic.trace import Trace
+    monkeypatch.setattr(S, "EXACT_CLOCKS", True)
+    caches = (replay._multi_segment_runner, replay._multi_init)
+    for f in caches:
+        f.cache_clear()
+    policy = Policy(kind="perfbound", bound=0.01, hist_mode="keep_all",
+                    sleep_state="deep_sleep")
+    topo = FatTree(k=8)
+    tr = Trace(nodes=np.arange(32, dtype=np.int64), name="ring")
+    for r in range(3):
+        tr.compute(1e-5)
+        tr.messages([[i, (i + 5 + r) % 32, 4096] for i in range(32)],
+                    barrier=r == 2)
+    batch = stack_plans([compile_plan(tr, topo)])
+    proto, params, carry = replay.init_lanes_multi([policy], batch)
+    seg = next(s for s in batch.segments if s.cap)
+    run = replay._multi_segment_runner(proto, PowerModel(), batch.n_links,
+                                       seg.cap, "prefix", seg.needs_sort)
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (carry[0], params, carry[1], carry[2], carry[3], batch.part_mask,
+         replay.segment_xs(seg, proto)))
+    try:
+        text = run.lower(*args).compile().as_text()
+    finally:
+        for f in caches:
+            f.cache_clear()
+    hist = f"{topo.n_links + 1},{policy.hist_bins}]"
+    op = re.compile(r"^\s+%\S+ = (.*?) (copy|copy-start|select)\(")
+    whole, entry = [], False
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            entry = line.startswith("ENTRY")
+            continue
+        m = op.match(line)
+        if m and hist in m.group(1) and not entry:
+            whole.append(line.strip()[:120])
+    assert hist in text and "while(" in text
+    assert whole == []

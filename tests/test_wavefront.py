@@ -264,6 +264,39 @@ def test_replay_programs_are_named_by_executor(mode):
     assert f"module @jit_replay_{mode}" in text
 
 
+@pytest.mark.parametrize("T", [1, 2])
+def test_trace_stack_updates_histograms_in_place(T):
+    """The multi-trace ``prefix`` program of a keep_all PerfBound lane
+    selects no histogram whole: its loop bound is reduced over the traces
+    before the trace vmap and its steps take no message branch, so nothing
+    lowers to a select between old and new carries that would make XLA
+    copy the histograms before each scatter."""
+    topo = TOPOS["fattree"]
+    pol = Policy(kind="perfbound", bound=0.01, hist_mode="keep_all",
+                 sleep_state="deep_sleep")
+    plans = []
+    for r in range(1, T + 1):
+        tr = Trace(nodes=np.arange(8, dtype=np.int64), name=f"t{r}")
+        tr.compute(1e-5)
+        tr.messages([[i, (i + r) % 8, 2048] for i in range(8)],
+                    barrier=True)
+        plans.append(P.compile_plan(tr, topo))
+    batch = P.stack_plans(plans)
+    seg = next(s for s in batch.segments if s.cap)
+    proto, params, carry = replay.init_lanes_multi([pol], batch)
+    run = replay._multi_segment_runner(proto, PM, batch.n_links, seg.cap,
+                                       "prefix", seg.needs_sort)
+    text = run.lower(carry[0], params, carry[1], carry[2], carry[3],
+                     batch.part_mask, replay.segment_xs(seg, proto)).as_text()
+    hist = f"x{topo.n_links + 1}x{pol.hist_bins}xf64>"
+    assert carry[0]["pred"]["counts"].shape[-2:] \
+        == (topo.n_links + 1, pol.hist_bins)
+    assert "stablehlo.while" in text and "stablehlo.scatter" in text
+    selects = [ln for ln in text.splitlines()
+               if "stablehlo.select" in ln and ln.rstrip().endswith(hist)]
+    assert selects == []
+
+
 # ---------------------------------------------------------------------------
 # Device residency: warm wavefront replay = 0 compiles, 0 transfers
 # ---------------------------------------------------------------------------
